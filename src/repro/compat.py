@@ -1,95 +1,34 @@
-"""Version-compat shims over the jax public API.
+"""The few jax entry points every call site shares, as one-liners.
 
-The codebase targets the modern jax surface (``jax.shard_map`` with
-``check_vma=``, ``jax.make_mesh(..., axis_types=...)`` with
-``jax.sharding.AxisType``).  Older installs (e.g. jax 0.4.x) only have
-``jax.experimental.shard_map.shard_map`` with ``check_rep=`` and a
-``jax.make_mesh`` that takes no ``axis_types``.  Every call site routes
-through this module so the rest of the tree can stay written against the
-new API.
+``shard_map`` fixes the keyword set the per-shard code uses, ``make_mesh``
+keeps meshes ``Auto`` (jax's default is ``Explicit``, which the shard_map'd
+programs here are not written for), and ``tpu_compiler_params`` keeps the
+Pallas TPU import out of module import time.
 """
 from __future__ import annotations
 
-import inspect
-from typing import Any, Optional
+from typing import Any
 
 import jax
 
-__all__ = ["shard_map", "make_mesh", "tpu_compiler_params", "cost_analysis",
-           "axis_size"]
-
-
-_HAS_TOP_LEVEL_SHARD_MAP = hasattr(jax, "shard_map")
-
-if not _HAS_TOP_LEVEL_SHARD_MAP:
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
+__all__ = ["shard_map", "make_mesh", "tpu_compiler_params"]
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` on new jax; the experimental one on old jax.
-
-    ``check_vma`` (new name) maps onto ``check_rep`` (old name) — both turn
-    off the replication/varying-manual-axes check that the per-shard code
-    here does not satisfy (it returns unreduced partials on purpose).
-    """
-    if _HAS_TOP_LEVEL_SHARD_MAP:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma)
-
-
-def _axis_types_auto(n: int) -> Optional[tuple]:
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return None
-    return (axis_type.Auto,) * n
-
-
-_MAKE_MESH_HAS_AXIS_TYPES = (
-    hasattr(jax, "make_mesh")
-    and "axis_types" in inspect.signature(jax.make_mesh).parameters
-)
+    """``jax.shard_map``; ``check_vma=False`` where per-shard code returns
+    unreduced partials on purpose."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def make_mesh(axis_shapes, axis_names, **kwargs: Any):
-    """``jax.make_mesh`` with ``axis_types=Auto`` where supported.
-
-    Old jax has neither the kwarg nor ``jax.sharding.AxisType``; meshes there
-    are implicitly Auto, so dropping the kwarg preserves semantics.
-    """
-    if _MAKE_MESH_HAS_AXIS_TYPES:
-        kwargs.setdefault("axis_types", _axis_types_auto(len(axis_shapes)))
-        return jax.make_mesh(axis_shapes, axis_names, **kwargs)
-    kwargs.pop("axis_types", None)
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(axis_shapes, axis_names, **kwargs)
-    from jax.experimental import mesh_utils
-
-    devices = mesh_utils.create_device_mesh(tuple(axis_shapes))
-    return jax.sharding.Mesh(devices, tuple(axis_names))
-
-
-def axis_size(axis_name):
-    """``jax.lax.axis_size`` (new); psum of a unit constant folds to the
-    same static size on old jax."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def cost_analysis(compiled) -> dict:
-    """Normalized ``compiled.cost_analysis()``: new jax returns a dict, old
-    jax a one-entry list of dicts (per program)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
+    """``jax.make_mesh`` with every axis ``Auto``."""
+    kwargs.setdefault("axis_types",
+                      (jax.sharding.AxisType.Auto,) * len(axis_shapes))
+    return jax.make_mesh(axis_shapes, axis_names, **kwargs)
 
 
 def tpu_compiler_params(**kwargs: Any):
-    """``pltpu.CompilerParams`` (new name) / ``pltpu.TPUCompilerParams`` (old)."""
     from jax.experimental.pallas import tpu as pltpu
 
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
+    return pltpu.CompilerParams(**kwargs)

@@ -285,7 +285,10 @@ class ParallelConfig:
     id_broadcast: bool = True   # §2.1a broadcast token ids not embeddings
     one_shot_sync: bool = True  # §2.2 single psum for parallel-residual
     zero_copy: bool = True      # §2.3 donation + fused epilogue
-    use_pallas: bool = False    # use Pallas kernels (interpret on CPU)
+    # Pallas kernels on the hot paths; None = on exactly when JAX's default
+    # backend is a TPU (resolved by ModelCtx.make).  Kernels run compiled
+    # on a TPU and in the Pallas interpreter on the CPU.
+    use_pallas: Optional[bool] = None
     flash_prefill: bool = True  # fused Pallas flash-prefill kernel on the
                                 # prefill hot path (effective with
                                 # use_pallas; the pure-JAX scan remains the

@@ -38,14 +38,14 @@ def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, m_ref, l_ref, acc_ref,
     q = q_ref[...].astype(jnp.float32)                   # (g, hd)
     k = k_ref[...].astype(jnp.float32)                   # (bs, hd)
     v = v_ref[...].astype(jnp.float32)                   # (bs, hd)
-    ok = valid_ref[...] != 0                             # (bs,)
+    ok = valid_ref[...] != 0                             # (1, bs)
 
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # (g, bs)
-    s = jnp.where(ok[None, :], s, NEG)
+    s = jnp.where(ok, s, NEG)
     m_prev = ms_ref[...][:, 0]                           # (g,)
     m_new = jnp.maximum(m_prev, s.max(axis=1))
     # explicit zeroing: on a fully-masked block exp(NEG - NEG) would be 1
-    p = jnp.where(ok[None, :], jnp.exp(s - m_new[:, None]), 0.0)
+    p = jnp.where(ok, jnp.exp(s - m_new[:, None]), 0.0)
     corr = jnp.exp(m_prev - m_new)
     l_new = ls_ref[...][:, 0] * corr + p.sum(axis=1)
     acc_new = as_ref[...] * corr[:, None] + jnp.dot(
@@ -88,7 +88,9 @@ def decode_attention_partial(
     qg = q.reshape(b, hkv, g, hd).reshape(b * hkv, g, hd)
     kg = k.reshape(b * hkv, Sp, hd)
     vg = v.reshape(b * hkv, Sp, hd)
-    vmask = valid.astype(jnp.int32)
+    # one (1, bs) row per cache block: the block's last two dims then equal
+    # the array's, which the TPU tiling rule accepts at any block size
+    vmask = valid.astype(jnp.int32).reshape(n_s, 1, bs)
     import jax.experimental.pallas.tpu as pltpu
 
     m, l, acc = pl.pallas_call(
@@ -98,7 +100,7 @@ def decode_attention_partial(
             pl.BlockSpec((None, g, hd), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((None, bs, hd), lambda i, j: (i, j, 0)),
             pl.BlockSpec((None, bs, hd), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((bs,), lambda i, j: (j,)),
+            pl.BlockSpec((None, 1, bs), lambda i, j: (j, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((None, g, 1), lambda i, j: (i, 0, 0)),
@@ -152,7 +154,7 @@ def _paged_decode_kernel(bt_ref, q_ref, k_ref, v_ref, valid_ref,
         ls_ref[...] = jnp.zeros_like(ls_ref)
         as_ref[...] = jnp.zeros_like(as_ref)
 
-    ok = valid_ref[...] != 0                             # (bs,)
+    ok = valid_ref[...] != 0                             # (1, bs)
 
     @pl.when(jnp.any(ok))
     def _update():
@@ -160,10 +162,10 @@ def _paged_decode_kernel(bt_ref, q_ref, k_ref, v_ref, valid_ref,
         k = k_ref[...].astype(jnp.float32)               # (bs, hd)
         v = v_ref[...].astype(jnp.float32)
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        s = jnp.where(ok[None, :], s, NEG)
+        s = jnp.where(ok, s, NEG)
         m_prev = ms_ref[...][:, 0]
         m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.where(ok[None, :], jnp.exp(s - m_new[:, None]), 0.0)
+        p = jnp.where(ok, jnp.exp(s - m_new[:, None]), 0.0)
         corr = jnp.exp(m_prev - m_new)
         l_new = ls_ref[...][:, 0] * corr + p.sum(axis=1)
         acc_new = as_ref[...] * corr[:, None] + jnp.dot(
@@ -199,7 +201,7 @@ def paged_decode_attention_partial(
     nbps = bt.shape[1]
     g = hq // hkv
     qg = q.reshape(b, hkv, g, hd).reshape(b * hkv, g, hd)
-    vmask = valid.reshape(b, nbps, bs).astype(jnp.int32)
+    vmask = valid.reshape(b, nbps, 1, bs).astype(jnp.int32)  # see above
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -212,8 +214,8 @@ def paged_decode_attention_partial(
             pl.BlockSpec((None, None, bs, hd),
                          lambda i, j, bt_ref: (bt_ref[i // hkv, j],
                                                i % hkv, 0, 0)),
-            pl.BlockSpec((None, None, bs),
-                         lambda i, j, bt_ref: (i // hkv, j, 0)),
+            pl.BlockSpec((None, None, 1, bs),
+                         lambda i, j, bt_ref: (i // hkv, j, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((None, g, 1), lambda i, j, bt_ref: (i, 0, 0)),

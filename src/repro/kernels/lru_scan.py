@@ -22,7 +22,7 @@ from repro import compat
 
 
 def _lru_kernel(a_ref, b_ref, h0_ref, out_ref, hT_ref, *, seq: int):
-    h = h0_ref[...].astype(jnp.float32)                  # (1, bw)
+    h = h0_ref[0].astype(jnp.float32)                    # (1, bw)
 
     def body(t, h):
         a_t = a_ref[0, t, :].astype(jnp.float32)         # (bw,)
@@ -32,7 +32,7 @@ def _lru_kernel(a_ref, b_ref, h0_ref, out_ref, hT_ref, *, seq: int):
         return h
 
     h = jax.lax.fori_loop(0, seq, body, h)
-    hT_ref[...] = h.astype(hT_ref.dtype)
+    hT_ref[0] = h.astype(hT_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_w", "interpret"))
@@ -48,7 +48,10 @@ def lru_scan(a: jax.Array, b: jax.Array, h0: jax.Array, *,
         b = jnp.pad(b, ((0, 0), (0, 0), (0, pad_w)))
         h0 = jnp.pad(h0, ((0, 0), (0, pad_w)))
     Wp = w + pad_w
-    import jax.experimental.pallas.tpu as pltpu
+    # the carried state rides as (batch, 1, width): a (1, bw) block of its
+    # last two dims meets the TPU tiling rule, a one-row (batch, width)
+    # block does not
+    h0 = h0[:, None, :]
 
     hs, hT = pl.pallas_call(
         functools.partial(_lru_kernel, seq=s),
@@ -56,19 +59,19 @@ def lru_scan(a: jax.Array, b: jax.Array, h0: jax.Array, *,
         in_specs=[
             pl.BlockSpec((1, s, bw), lambda i, j: (i, 0, j)),
             pl.BlockSpec((1, s, bw), lambda i, j: (i, 0, j)),
-            pl.BlockSpec((1, bw), lambda i, j: (i, j)),
+            pl.BlockSpec((1, 1, bw), lambda i, j: (i, 0, j)),
         ],
         out_specs=[
             pl.BlockSpec((1, s, bw), lambda i, j: (i, 0, j)),
-            pl.BlockSpec((1, bw), lambda i, j: (i, j)),
+            pl.BlockSpec((1, 1, bw), lambda i, j: (i, 0, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bsz, s, Wp), jnp.float32),
-            jax.ShapeDtypeStruct((bsz, Wp), jnp.float32),
+            jax.ShapeDtypeStruct((bsz, 1, Wp), jnp.float32),
         ],
         compiler_params=compat.tpu_compiler_params(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
     )(a, b, h0)
-    return hs[..., :w], hT[..., :w]
+    return hs[..., :w], hT[:, 0, :w]

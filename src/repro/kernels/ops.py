@@ -1,12 +1,10 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On this CPU container the kernels always run with interpret=True (the kernel
-body executes in Python, validating the exact TPU program); on a real TPU set
-``REPRO_PALLAS_INTERPRET=0`` to lower to Mosaic.
+Interpret mode follows the platform: on the CPU backend the kernel body runs
+in the Pallas interpreter (the exact TPU program, executed in Python); on
+any other backend the kernels lower to Mosaic and run compiled.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -15,23 +13,26 @@ from repro.kernels import decode_attention as _da
 from repro.kernels import fused_residual as _fr
 from repro.kernels import topk_shard as _tk
 
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
+
+def interpret() -> bool:
+    """True when the kernels run in the Pallas interpreter (CPU backend)."""
+    return jax.default_backend() == "cpu"
 
 
 def topk(x: jax.Array, k: int):
     """(batch, v_local) -> (vals (batch,k) fp32, idx (batch,k) int32)."""
-    return _tk.topk(x, k, interpret=INTERPRET)
+    return _tk.topk(x, k, interpret=interpret())
 
 
 def fused_dual_matmul(a, wa, b, wb):
     """(T,Ka)@(Ka,D) + (T,Kb)@(Kb,D) accumulated in one output tile."""
-    return _fr.fused_dual_matmul(a, wa, b, wb, interpret=INTERPRET)
+    return _fr.fused_dual_matmul(a, wa, b, wb, interpret=interpret())
 
 
 def decode_attention_partial(q, k, v, valid, scale):
     """Flash partials (m, l, acc) for one decode token over the cache."""
     return _da.decode_attention_partial(q, k, v, valid, float(scale),
-                                        interpret=INTERPRET)
+                                        interpret=interpret())
 
 
 def paged_decode_attention(q, kp, vp, bt, valid, scale):
@@ -39,7 +40,7 @@ def paged_decode_attention(q, kp, vp, bt, valid, scale):
     block from the paged pool through the slot's block table."""
     return _da.paged_decode_attention_partial(q, kp, vp, bt, valid,
                                               float(scale),
-                                              interpret=INTERPRET)
+                                              interpret=interpret())
 
 
 def flash_prefill(q, k, v, q_pos, scale):
@@ -49,7 +50,7 @@ def flash_prefill(q, k, v, q_pos, scale):
     from repro.kernels import prefill_attention as _pa
 
     return _pa.flash_prefill(q, k, v, q_pos, float(scale),
-                             interpret=INTERPRET)
+                             interpret=interpret())
 
 
 def paged_flash_prefill(q, kp, vp, bt, q_pos, scale):
@@ -59,7 +60,7 @@ def paged_flash_prefill(q, kp, vp, bt, q_pos, scale):
     from repro.kernels import prefill_attention as _pa
 
     return _pa.paged_flash_prefill(q, kp, vp, bt, q_pos, float(scale),
-                                   interpret=INTERPRET)
+                                   interpret=interpret())
 
 
 def flash_verify(q, k, v, q_pos, scale):
@@ -71,14 +72,14 @@ def flash_verify(q, k, v, q_pos, scale):
     from repro.kernels import prefill_attention as _pa
 
     return _pa.flash_verify(q, k, v, q_pos, float(scale),
-                            interpret=INTERPRET)
+                            interpret=interpret())
 
 
 def lru_scan(a, b, h0):
     """RG-LRU linear-recurrence scan: h_t = a_t h_{t-1} + b_t."""
     from repro.kernels import lru_scan as _ls
 
-    return _ls.lru_scan(a, b, h0, interpret=INTERPRET)
+    return _ls.lru_scan(a, b, h0, interpret=interpret())
 
 
 def dequant_matmul(x, q, scale, *, mode, group, out_dtype=None):
@@ -90,4 +91,4 @@ def dequant_matmul(x, q, scale, *, mode, group, out_dtype=None):
     from repro.kernels import wquant_matmul as _wq
 
     return _wq.dequant_matmul(x, q, scale, mode=mode, group=int(group),
-                              out_dtype=out_dtype, interpret=INTERPRET)
+                              out_dtype=out_dtype, interpret=interpret())

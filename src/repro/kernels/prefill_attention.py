@@ -45,7 +45,7 @@ NEG = -3.0e38
 def _flash_update(qp_ref, q_ref, k_ref, v_ref, ms_ref, ls_ref, as_ref,
                   *, scale: float, kv0, bk: int, kv_limit: int):
     """One (block_q, g) x (block_k,) flash step against running stats."""
-    qpos = qp_ref[...]                                    # (bq,)
+    qpos = qp_ref[...]                                    # (bq, 1)
 
     @pl.when(kv0 <= jnp.max(qpos))
     def _update():
@@ -57,7 +57,7 @@ def _flash_update(qp_ref, q_ref, k_ref, v_ref, ms_ref, ls_ref, as_ref,
                     preferred_element_type=jnp.float32)
         s = (s * scale).reshape(bq, g, bk)
         kvpos = kv0 + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bk), 2)
-        ok = (kvpos <= qpos[:, None, None]) & (kvpos < kv_limit)
+        ok = (kvpos <= qpos[:, :, None]) & (kvpos < kv_limit)
         s = jnp.where(ok, s, NEG)
         m_prev = ms_ref[...]                              # (bq, g)
         m_new = jnp.maximum(m_prev, s.max(axis=-1))
@@ -99,6 +99,13 @@ def _fold_q(q: jax.Array, hkv: int):
     return (q.reshape(b, hkv, g, sq, hd)
              .transpose(0, 1, 3, 2, 4)
              .reshape(b * hkv, sq, g, hd))
+
+
+def _column(q_pos: jax.Array):
+    """(b, Sq) -> (b, Sq, 1) int32: a (bq, 1) block per query tile keeps the
+    tile's rows on sublanes, which the TPU tiling rule accepts for any
+    sublane-aligned bq."""
+    return q_pos.astype(jnp.int32)[..., None]
 
 
 def _unfold_o(o: jax.Array, b: int, hkv: int, sq: int):
@@ -146,6 +153,7 @@ def flash_prefill(
     sqp, skp = sq + pad_q, sk + pad_k
     n_q, n_k = sqp // bq, skp // bk
     qg = _fold_q(q, hkv)
+    qp = _column(q_pos)
     kg = k.reshape(b * hkv, skp, hd)
     vg = v.reshape(b * hkv, skp, hd)
 
@@ -154,7 +162,8 @@ def flash_prefill(
                           kv_limit=sk),
         grid=(b * hkv, n_q, n_k),
         in_specs=[
-            pl.BlockSpec((None, bq), lambda i, qi, j, hkv=hkv: (i // hkv, qi)),
+            pl.BlockSpec((None, bq, 1),
+                         lambda i, qi, j, hkv=hkv: (i // hkv, qi, 0)),
             pl.BlockSpec((None, bq, g, hd), lambda i, qi, j: (i, qi, 0, 0)),
             pl.BlockSpec((None, bk, hd), lambda i, qi, j: (i, j, 0)),
             pl.BlockSpec((None, bk, hd), lambda i, qi, j: (i, j, 0)),
@@ -171,7 +180,7 @@ def flash_prefill(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(q_pos.astype(jnp.int32), qg, kg, vg)
+    )(qp, qg, kg, vg)
     return _unfold_o(o, b, hkv, sq)
 
 
@@ -239,8 +248,8 @@ def paged_flash_prefill(
         num_scalar_prefetch=1,
         grid=(b * hkv, n_q, nbps),
         in_specs=[
-            pl.BlockSpec((None, bq),
-                         lambda i, qi, j, bt_ref: (i // hkv, qi)),
+            pl.BlockSpec((None, bq, 1),
+                         lambda i, qi, j, bt_ref: (i // hkv, qi, 0)),
             pl.BlockSpec((None, bq, g, hd),
                          lambda i, qi, j, bt_ref: (i, qi, 0, 0)),
             pl.BlockSpec((None, None, bs, hd),
@@ -272,7 +281,7 @@ def paged_flash_prefill(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(bt.astype(jnp.int32), q_pos.astype(jnp.int32), qg, kp, vp)
+    )(bt.astype(jnp.int32), _column(q_pos), qg, kp, vp)
     return _unfold_o(o, b, hkv, sq)
 
 

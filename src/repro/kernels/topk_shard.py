@@ -39,20 +39,30 @@ def _topk_kernel(x_ref, vals_ref, idx_ref, rv_ref, ri_ref, *, k: int, block_v: i
     # mask out-of-range tail (vocab padded to block multiple)
     x = jnp.where(cols < v_local, x, NEG)
 
-    # candidates = running (bb,k) ++ tile (bb,block_v)
-    cand_v = jnp.concatenate([rv_ref[...], x], axis=1)
-    cand_i = jnp.concatenate([ri_ref[...], cols], axis=1)
-
+    # The candidates are running (bb,k) followed by tile (bb,block_v); each
+    # pass extracts the first maximum of that sequence.  The two parts are
+    # kept apart (no lane concatenation) and positions are found by iota
+    # compares (no gather), both of which the TPU compiler lowers.
+    rv, ri = rv_ref[...], ri_ref[...]
+    kcol = jax.lax.broadcasted_iota(jnp.int32, (bb, k), 1)
+    vcol = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
     new_v = jnp.zeros((bb, k), jnp.float32)
     new_i = jnp.zeros((bb, k), jnp.int32)
     for t in range(k):                                   # unrolled: k small
-        m = jnp.max(cand_v, axis=1)                      # (bb,)
-        am = jnp.argmax(cand_v, axis=1)                  # (bb,)
-        picked_i = jnp.take_along_axis(cand_i, am[:, None], axis=1)[:, 0]
-        new_v = new_v.at[:, t].set(m)
-        new_i = new_i.at[:, t].set(picked_i)
-        onehot = jax.lax.broadcasted_iota(jnp.int32, cand_v.shape, 1) == am[:, None]
-        cand_v = jnp.where(onehot, NEG, cand_v)
+        mr = jnp.max(rv, axis=1, keepdims=True)          # (bb, 1)
+        mx = jnp.max(x, axis=1, keepdims=True)
+        from_r = mr >= mx                                # ties: running first
+        ar = jnp.min(jnp.where(rv == mr, kcol, k), axis=1, keepdims=True)
+        ax = jnp.min(jnp.where(x == mx, vcol, block_v), axis=1, keepdims=True)
+        hit_r = kcol == ar
+        hit_x = vcol == ax
+        idx_r = jnp.sum(jnp.where(hit_r, ri, 0), axis=1, keepdims=True)
+        picked_v = jnp.where(from_r, mr, mx)
+        picked_i = jnp.where(from_r, idx_r, col0 + ax)
+        new_v = jnp.where(kcol == t, picked_v, new_v)
+        new_i = jnp.where(kcol == t, picked_i, new_i)
+        rv = jnp.where(from_r & hit_r, NEG, rv)
+        x = jnp.where(~from_r & hit_x, NEG, x)
     rv_ref[...] = new_v
     ri_ref[...] = new_i
 

@@ -123,9 +123,13 @@ def dequant_matmul(
             raise ValueError("int4 K must be a multiple of the group")
         qp = jnp.pad(q, ((0, 0), (0, pad_n))) if pad_n else q
         sp = jnp.pad(scale, ((0, 0), (0, pad_n))) if pad_n else scale
+        # one (1, bn) scale row per group step: as the last two dims of a
+        # (K/g, 1, N) array the block meets the TPU tiling rule, where a
+        # one-row block of the (K/g, N) array does not
+        sp = sp[:, None, :]
         kernel = functools.partial(_dq4_kernel, n_k=n_k)
         q_spec = pl.BlockSpec((bk // 2, bn), lambda i, j, kk: (kk, j))
-        s_spec = pl.BlockSpec((1, bn), lambda i, j, kk: (kk, j))
+        s_spec = pl.BlockSpec((None, 1, bn), lambda i, j, kk: (kk, 0, j))
     else:
         qp = jnp.pad(q, ((0, pad_k), (0, pad_n)))
         sp = jnp.pad(scale[None, :], ((0, 0), (0, pad_n)))

@@ -219,7 +219,7 @@ def build_lowered(arch: str, shape_name: str, *, multi_pod: bool,
 
 def analyze(lowered, compiled, ctx, shape, *, t_compile: float) -> dict:
     mem = compiled.memory_analysis()
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis() or {}
     hlo = compiled.as_text()
     colls = parse_collectives(hlo)
     from repro.core.zero_copy import count_copies
@@ -262,7 +262,7 @@ def _cost_probe(arch, shape_name, multi_pod, n_layers, overrides):
         compiled = lowered.compile()
     finally:
         UNROLL_SCANS.reset(token)
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis() or {}
     colls = parse_collectives(compiled.as_text())
     return {
         "flops": cost.get("flops", 0.0),

@@ -27,7 +27,10 @@ interactive may use, the 429 ``Retry-After`` hint scales per class
 (batch backs off longest), and while the scheduler's degradation ladder
 is shedding batch (level 1+), batch submissions are rejected at the door.
 **Graceful drain**: shutdown stops accepting (503), serves every admitted
-request to completion, then exits.
+request to completion, then exits.  **Engine failure**: if a scheduler
+round raises, the worker records the exception, fails every open request
+with 500, ``/health`` reports ``failed`` (503) with the error, new
+submissions get 503, and ``drain()`` re-raises the exception.
 
 The API accepts token-id prompts (this repo has no tokenizer):
 
@@ -43,8 +46,10 @@ from __future__ import annotations
 import argparse
 import asyncio
 import json
+import sys
 import threading
 import time
+import traceback
 from typing import List, Optional
 
 import numpy as np
@@ -129,6 +134,8 @@ class EngineService:
         self._streams = {}
         self._live = 0               # submitted (inbox or in-engine), unfinished
         self._draining = False
+        # the exception that killed the worker thread (None while healthy)
+        self.error: Optional[BaseException] = None
         self._wake = threading.Event()
         self._thread: Optional[threading.Thread] = None
         scheduler.stats.setdefault("shed_requests", 0)
@@ -180,8 +187,9 @@ class EngineService:
                    stream: TokenStream,
                    deadline_s: Optional[float] = None,
                    priority: str = "standard") -> str:
-        """Returns "ok", "shed" (bounded-queue overload), "draining", or
-        "wedged" (watchdog tripped — the engine stopped making progress).
+        """Returns "ok", "shed" (bounded-queue overload), "draining",
+        "wedged" (watchdog tripped — the engine stopped making progress) or
+        "failed" (a scheduler round raised; the worker is gone).
 
         Class-aware shedding, lowest class first: while the scheduler's
         degradation ladder sheds batch, batch is rejected at the door; a
@@ -194,6 +202,10 @@ class EngineService:
             return "wedged"
         rank = PRIORITY_RANK[priority]
         with self._lock:
+            # under the lock: _fail() empties the inbox under it too, so no
+            # request can slip in behind a dead worker
+            if self.error is not None:
+                return "failed"
             if self._draining:
                 return "draining"
             if (priority == "batch" and self.sched.overload_level() >= 1):
@@ -245,7 +257,11 @@ class EngineService:
                     stream.fail(str(e))
                     continue
                 self._streams[rid] = stream
-            progressed = self.sched.serve_step()
+            try:
+                progressed = self.sched.serve_step()
+            except Exception as e:       # the engine's boundary: report it
+                self._fail(e)
+                return
             if progressed:
                 continue
             with self._lock:
@@ -257,6 +273,20 @@ class EngineService:
                 self._wake.wait(self.idle_wait_s)
                 self._wake.clear()
 
+    def _fail(self, e: Exception) -> None:
+        """Worker-thread death: record ``e`` and fail every open request."""
+        sys.stderr.write("engine worker failed:\n" + traceback.format_exc())
+        msg = f"engine step failed: {type(e).__name__}: {e}"
+        with self._lock:
+            self.error = e
+            inbox, self._inbox = self._inbox, []
+            streams = list(self._streams.values()) + [x[4] for x in inbox]
+            self._streams.clear()
+            self._live = 0
+        for stream in streams:
+            stream.fail(msg, status="500 Internal Server Error",
+                        err_type="engine_error")
+
     def start(self) -> None:
         if self._thread is not None:
             return
@@ -266,14 +296,16 @@ class EngineService:
 
     def drain(self, timeout: Optional[float] = None) -> bool:
         """Graceful shutdown: reject new work, serve everything admitted,
-        join the worker.  Returns True if the worker exited in time."""
+        join the worker.  Returns True if the worker exited in time; raises
+        the worker's exception if a scheduler round failed."""
         with self._lock:
             self._draining = True
         self._wake.set()
-        if self._thread is None:
-            return True
-        self._thread.join(timeout)
-        return not self._thread.is_alive()
+        if self._thread is not None:
+            self._thread.join(timeout)
+        if self.error is not None:
+            raise self.error
+        return self._thread is None or not self._thread.is_alive()
 
 
 class HttpFrontend:
@@ -353,12 +385,13 @@ class HttpFrontend:
                 # last_step_age_s is seconds since engine outputs last
                 # became host-visible — the scheduler watchdog signal
                 svc = self.service
+                failed = svc.error is not None
                 wedged = svc.wedged()
                 with svc._lock:
                     inbox_depth = len(svc._inbox)
                     draining = svc._draining
                 payload = {
-                    "status": ("wedged" if wedged
+                    "status": ("failed" if failed else "wedged" if wedged
                                else "draining" if draining else "ok"),
                     "pending": svc.pending(),
                     "inbox_depth": inbox_depth,
@@ -383,8 +416,11 @@ class HttpFrontend:
                     "arch": svc.sched.engine.cfg.name,
                     "capabilities": _capability_matrix(),
                 }
+                if failed:
+                    payload["error"] = (f"{type(svc.error).__name__}: "
+                                        f"{svc.error}")
                 self._respond(writer,
-                              "503 Service Unavailable" if wedged
+                              "503 Service Unavailable" if failed or wedged
                               else "200 OK", payload)
             elif method == "POST" and path == "/v1/completions":
                 await self._completions(writer, body)
@@ -433,6 +469,12 @@ class HttpFrontend:
         verdict = self.service.try_submit(prompt, max_new, eos_id, stream,
                                           deadline_s=max_time,
                                           priority=priority)
+        if verdict == "failed":
+            self._respond(writer, "503 Service Unavailable",
+                          {"error": {"message": "engine failed: "
+                                                f"{self.service.error}",
+                                     "type": "engine_error"}})
+            return
         if verdict == "wedged":
             # scheduler watchdog tripped: the engine stopped producing
             # output with work in flight — fail fast so the load balancer
@@ -507,13 +549,18 @@ class HttpFrontend:
             "usage": {"completion_tokens": len(toks)}})
 
     async def _stream_response(self, writer, cid, eos_id, stream) -> None:
+        # the status line waits for the first token, so a request that fails
+        # before it emits anything gets its own status (e.g. 500), not 200
+        t = await stream.next_token()
+        if t is None and stream.error is not None:
+            self._respond(writer, stream.error_status,
+                          {"error": {"message": stream.error,
+                                     "type": stream.error_type}})
+            return
         writer.write(b"HTTP/1.1 200 OK\r\nContent-Type: text/event-stream\r\n"
                      b"Cache-Control: no-cache\r\nConnection: close\r\n\r\n")
         toks: List[int] = []
-        while True:
-            t = await stream.next_token()
-            if t is None:
-                break
+        while t is not None:
             toks.append(t)
             chunk = {"id": cid, "object": "text_completion.chunk",
                      "choices": [{"index": 0, "token_ids": [t], "text": ""}]}
@@ -522,6 +569,7 @@ class HttpFrontend:
                 await writer.drain()
             except ConnectionError:
                 return                # client went away; engine finishes solo
+            t = await stream.next_token()
         if stream.error is not None:
             writer.write(
                 f"data: {json.dumps({'error': stream.error})}\n\n".encode())
@@ -542,6 +590,9 @@ class HttpFrontend:
 
 def main(argv=None):
     from repro.launch import serve as serve_mod
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     ap = serve_mod.build_parser(argparse.ArgumentParser(
         description="OpenAI-compatible async serving frontend"))
     ap.add_argument("--host", default="127.0.0.1")

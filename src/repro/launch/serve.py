@@ -51,16 +51,20 @@ import jax
 import numpy as np
 
 from repro.configs import ParallelConfig, SamplingConfig, get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.runtime.engine import Engine
 from repro.runtime.scheduler import (ContinuousScheduler, DisaggScheduler,
                                      PagedContinuousScheduler, WaveScheduler)
 
 
-def build_engine(args):
-    cfg = get_config(args.arch)
-    if not args.full:
-        cfg = cfg.reduced()
+def build_engine(args, cfg=None):
+    """Engine for ``args``; ``cfg`` overrides the config ``--arch`` /
+    ``--full`` would pick (e.g. a depth cut at published widths)."""
+    if cfg is None:
+        cfg = get_config(args.arch)
+        if not args.full:
+            cfg = cfg.reduced()
     mesh = make_local_mesh(args.dp, args.tp)
     par = ParallelConfig(tp=args.tp, dp=args.dp, remat=False,
                          topk_sync=not args.no_topk_sync,
@@ -344,6 +348,7 @@ def dump_stats_json(sched, path, extra=None):
 
 
 def main(argv=None):
+    use_compile_cache()
     ap = build_parser()
     args = ap.parse_args(argv)
     if args.list_archs:

@@ -493,9 +493,7 @@ def _write_prefill(cache_side: jax.Array, new: jax.Array, positions: jax.Array, 
     new = new.astype(cache_side.dtype)
     s = new.shape[2]
     if seq_axis is not None:
-        from repro import compat
-
-        ns = compat.axis_size(seq_axis)
+        ns = jax.lax.axis_size(seq_axis)
         if s > S * ns:
             raise ValueError(f"seq-sharded prefill needs s <= S*shards ({s} > {S}*{ns})")
         if s < S * ns:  # pad; padded slots keep pos = -1 (masked, decode-writable)

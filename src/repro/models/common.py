@@ -15,7 +15,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ParallelConfig
 
@@ -132,17 +132,43 @@ class ParamDef:
             return jnp.ones(self.shape, self.dtype)
         fan_in = self.shape[self.scale_dim] if self.init == "scaled" else None
         std = 0.02 if fan_in is None else 1.0 / math.sqrt(fan_in)
-        return (jax.random.normal(key, self.shape, jnp.float32) * std).astype(self.dtype)
+        # reduce_precision to fp32's own width changes no value; it keeps
+        # XLA from folding ``std`` into the draw's own sqrt(2) factor inside
+        # a fused program, which would move the rounding of the product
+        draw = jax.lax.reduce_precision(
+            jax.random.normal(key, self.shape, jnp.float32), 8, 23)
+        return (draw * std).astype(self.dtype)
 
 
 def is_def(x) -> bool:
     return isinstance(x, ParamDef)
 
 
-def materialize(defs: Pytree, key) -> Pytree:
+def materialize(defs: Pytree, key, mesh=None) -> Pytree:
+    """Draw every leaf of ``defs`` from ``key`` in one jitted program.
+
+    With a ``mesh``, each leaf is constrained to ``NamedSharding(mesh,
+    spec)`` inside the program, so every device draws only its own shard (the
+    threefry PRNG is partitionable: values do not depend on the sharding)
+    and no leaf passes through one device whole.  Inside the program the
+    fp32 draw, scale and cast fuse, so no fp32 copy of a leaf is kept."""
     leaves, treedef = jax.tree.flatten(defs, is_leaf=is_def)
-    keys = jax.random.split(key, len(leaves))
-    return jax.tree.unflatten(treedef, [d.initialize(k) for d, k in zip(leaves, keys)])
+    sig = tuple((d.shape, d.init, d.scale_dim, jnp.dtype(d.dtype).name)
+                for d in leaves)
+    shardings = (None if mesh is None
+                 else tuple(NamedSharding(mesh, d.spec) for d in leaves))
+    return jax.tree.unflatten(treedef, _draw_leaves(key, sig, shardings))
+
+
+@partial(jax.jit, static_argnums=(1, 2))
+def _draw_leaves(key, sig, shardings):
+    keys = jax.random.split(key, len(sig))
+    out = [ParamDef(shape, P(), init, scale_dim, dtype).initialize(k)
+           for (shape, init, scale_dim, dtype), k in zip(sig, keys)]
+    if shardings is not None:
+        out = [jax.lax.with_sharding_constraint(x, s)
+               for x, s in zip(out, shardings)]
+    return out
 
 
 def specs_of(defs: Pytree) -> Pytree:

@@ -5,6 +5,7 @@ Everything here is per-shard code for ``jax.shard_map``; the launcher
 """
 from __future__ import annotations
 
+import dataclasses
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
 
@@ -43,6 +44,9 @@ class ModelCtx:
     @staticmethod
     def make(cfg: ModelConfig, parallel: ParallelConfig,
              *, pod_axis: Optional[str] = None) -> "ModelCtx":
+        if parallel.use_pallas is None:
+            parallel = dataclasses.replace(
+                parallel, use_pallas=jax.default_backend() == "tpu")
         dist = Dist(
             model_axis="model", data_axis="data", pod_axis=pod_axis,
             tp=parallel.tp, dp=parallel.dp, pods=parallel.pods,
@@ -82,8 +86,9 @@ def model_defs(ctx: ModelCtx) -> Dict[str, Any]:
     return defs
 
 
-def init_params(ctx: ModelCtx, key) -> Pytree:
-    return materialize(model_defs(ctx), key)
+def init_params(ctx: ModelCtx, key, mesh=None) -> Pytree:
+    """Random parameters; with a ``mesh`` each leaf is created sharded."""
+    return materialize(model_defs(ctx), key, mesh)
 
 
 # ---------------------------------------------------------------------------

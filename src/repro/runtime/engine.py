@@ -522,6 +522,7 @@ class Engine:
     def __post_init__(self):
         pod = "pod" if "pod" in self.mesh.axis_names else None
         self.ctx = M.ModelCtx.make(self.cfg, self.parallel, pod_axis=pod)
+        self.parallel = self.ctx.parallel      # use_pallas resolved
         # the declarative capability record every scheduler/serve entry
         # consults (the single require() choke point for path eligibility)
         self.caps = ArchCapabilities.from_config(self.cfg)
@@ -532,7 +533,8 @@ class Engine:
                 self.params = M.load_quantized(self.ctx, self.wq_cache)
                 loaded = True
             else:
-                self.params = M.init_params(self.ctx, jax.random.key(self.seed))
+                self.params = M.init_params(self.ctx, jax.random.key(self.seed),
+                                            self.mesh)
         if wq:
             # quantize-at-load: the serving programs only ever see packed
             # weights + scales; param_specs mirrors the transform so the

@@ -282,6 +282,29 @@ def check_engine_tp():
     print("PASS engine_tp")
 
 
+def check_placed_init():
+    """Parameters built with a mesh land sharded by their specs (each
+    device holds only its share) and equal the unplaced draw bit for bit."""
+    from jax.sharding import NamedSharding
+
+    from repro.models.common import is_def
+
+    cfg = get_config("yi-9b").reduced()
+    mesh = _mesh(2, 4)
+    ctx = M.ModelCtx.make(cfg, ParallelConfig(tp=4, dp=2, remat=False))
+    placed = M.init_params(ctx, jax.random.key(0), mesh)
+    plain = M.init_params(ctx, jax.random.key(0))
+    defs = jax.tree.leaves(M.model_defs(ctx), is_leaf=is_def)
+    for d, x, y in zip(defs, jax.tree.leaves(placed), jax.tree.leaves(plain)):
+        want = NamedSharding(mesh, d.spec)
+        assert x.sharding.is_equivalent_to(want, x.ndim), (d.spec, x.sharding)
+        shard = want.shard_shape(x.shape)
+        assert all(s.data.shape == shard for s in x.addressable_shards)
+        np.testing.assert_array_equal(np.asarray(x, np.float32),
+                                      np.asarray(y, np.float32))
+    print("PASS placed_init")
+
+
 CHECKS = {k[6:]: v for k, v in list(globals().items()) if k.startswith("check_")}
 
 if __name__ == "__main__":

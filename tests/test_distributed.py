@@ -18,6 +18,7 @@ CHECKS = [
     "kv_seq_shard",
     "embed_modes",
     "engine_tp",
+    "placed_init",
 ]
 
 

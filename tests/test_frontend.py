@@ -37,8 +37,9 @@ def engine():
 
 
 @contextmanager
-def serving(engine, n_slots=2, max_pending=8):
-    sched = ContinuousScheduler(engine, n_slots=n_slots, block_steps=2)
+def serving(engine, n_slots=2, max_pending=8, sched=None):
+    if sched is None:
+        sched = ContinuousScheduler(engine, n_slots=n_slots, block_steps=2)
     service = EngineService(sched, max_pending=max_pending,
                             idle_wait_s=0.002)
     frontend = HttpFrontend(service, port=0)
@@ -58,11 +59,13 @@ def serving(engine, n_slots=2, max_pending=8):
     try:
         yield frontend, sched
     finally:
-        asyncio.run_coroutine_threadsafe(frontend.stop(),
-                                         loop).result(timeout=120)
-        loop.call_soon_threadsafe(loop.stop)
-        thread.join(timeout=30)
-        loop.close()
+        try:
+            asyncio.run_coroutine_threadsafe(frontend.stop(),
+                                             loop).result(timeout=120)
+        finally:
+            loop.call_soon_threadsafe(loop.stop)
+            thread.join(timeout=30)
+            loop.close()
 
 
 def post(port, body, timeout=120):
@@ -208,3 +211,40 @@ def test_graceful_drain(engine):
     assert len(toks) == 8 and finish == "length" and done
     with pytest.raises(OSError):
         post(port, {"prompt": [1, 2], "max_tokens": 1}, timeout=5)
+
+
+def test_engine_failure_fails_open_requests(engine):
+    """A scheduler round that raises (a step the device compiler refuses,
+    say) must not leave a live server over a dead engine: open requests
+    get 500, /health reports the error, new work gets 503, and drain()
+    re-raises the exception."""
+    sched = ContinuousScheduler(engine, n_slots=2, block_steps=2)
+
+    def serve_step():
+        # idle until both requests are open, then fail the round
+        if len(sched.queue) < 2:
+            return False
+        raise RuntimeError("step program refused")
+
+    sched.serve_step = serve_step
+    bodies = [{"prompt": prompt_for(engine.cfg, 40), "max_tokens": 4},
+              {"prompt": prompt_for(engine.cfg, 41), "max_tokens": 4,
+               "stream": True}]
+    with pytest.raises(RuntimeError, match="step program refused"):
+        with serving(engine, sched=sched) as (fe, _):
+            with ThreadPoolExecutor(2) as pool:
+                results = list(pool.map(lambda b: post(fe.port, b), bodies))
+            for st, _, data in results:
+                assert st == 500
+                err = json.loads(data)["error"]
+                assert err["type"] == "engine_error"
+                assert "step program refused" in err["message"]
+            c = http.client.HTTPConnection("127.0.0.1", fe.port, timeout=30)
+            c.request("GET", "/health")
+            r = c.getresponse()
+            assert r.status == 503
+            h = json.loads(r.read())
+            assert h["status"] == "failed"
+            assert "step program refused" in h["error"]
+            st, _, _ = post(fe.port, bodies[0], timeout=30)
+            assert st == 503

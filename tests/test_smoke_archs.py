@@ -133,13 +133,9 @@ def test_decode_matches_full_forward(arch):
 @pytest.mark.parametrize("arch", ["yi-9b", "qwen2.5-14b"])
 def test_int8_kv_cache_close_to_bf16(arch):
     """int8 KV cache (per-head-per-slot scales) stays within ~2% of bf16 on
-    dense archs (MoE archs are router-flip sensitive; documented).
-
-    NOTE: the seed-state failure of this test was NOT a quantization bug —
-    it was the jax-API skew (``jax.shard_map`` missing on jax 0.4.x), fixed
-    by routing through ``repro.compat``.  The scale path (absmax/127 per
-    (batch, head, slot), fp32 round-trip) verifies within the 5% bound on
-    both archs with no tolerance change."""
+    dense archs (MoE archs are router-flip sensitive; documented).  The
+    scale path is absmax/127 per (batch, head, slot) with an fp32
+    round-trip."""
     cfg = get_config(arch).reduced()
     mesh = make_local_mesh(1, 1)
     tokens = jax.random.randint(jax.random.key(1), (2, 17), 0, cfg.vocab_size)
