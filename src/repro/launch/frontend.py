@@ -54,6 +54,7 @@ from typing import List, Optional
 
 import numpy as np
 
+from repro.runtime import tracing
 from repro.runtime.scheduler import PRIORITY_CLASSES, PRIORITY_RANK
 
 _DONE = object()
@@ -232,31 +233,41 @@ class EngineService:
                     "request", status="429 Too Many Requests",
                     err_type="overloaded_error")
             self._inbox.append((prompt, max_new, eos_id, deadline_s, stream,
-                                priority))
+                                priority, time.monotonic()))
             self._live += 1
         self._wake.set()
         return "ok"
 
     # -- worker side --------------------------------------------------------
+    def _intake(self) -> int:
+        """Moves the inbox into the scheduler and returns how many requests
+        it took.  Each request's ``stats["inbox_s"]`` is its wait from
+        ``try_submit`` to the scheduler's ``submit``."""
+        with self._lock:
+            batch, self._inbox = self._inbox, []
+        for prompt, max_new, eos_id, deadline_s, stream, priority, t_in \
+                in batch:
+            try:
+                # arrival_step = now on the virtual clock: immediately
+                # admissible, ordering decided by the scheduler
+                rid = self.sched.submit(
+                    np.asarray(prompt, np.int32), max_new, eos_id=eos_id,
+                    arrival_step=self.sched.step_count,
+                    deadline_s=deadline_s, priority=priority)
+            except ValueError as e:
+                with self._lock:
+                    self._live -= 1
+                stream.fail(str(e))
+                continue
+            req = next(r for r in reversed(self.sched.queue) if r.rid == rid)
+            req.stats["inbox_s"] = req.submitted_at - t_in
+            self._streams[rid] = stream
+        return len(batch)
+
     def _worker(self) -> None:
         while True:
-            with self._lock:
-                batch, self._inbox = self._inbox, []
-            for prompt, max_new, eos_id, deadline_s, stream, priority \
-                    in batch:
-                try:
-                    # arrival_step = now on the virtual clock: immediately
-                    # admissible, ordering decided by the scheduler
-                    rid = self.sched.submit(
-                        np.asarray(prompt, np.int32), max_new, eos_id=eos_id,
-                        arrival_step=self.sched.step_count,
-                        deadline_s=deadline_s, priority=priority)
-                except ValueError as e:
-                    with self._lock:
-                        self._live -= 1
-                    stream.fail(str(e))
-                    continue
-                self._streams[rid] = stream
+            with tracing.span("service.intake") as sp:
+                sp.set_metadata(n=self._intake())
             try:
                 progressed = self.sched.serve_step()
             except Exception as e:       # the engine's boundary: report it
@@ -270,7 +281,8 @@ class EngineService:
             if stop:
                 return
             if idle:
-                self._wake.wait(self.idle_wait_s)
+                with tracing.span("service.wait"):
+                    self._wake.wait(self.idle_wait_s)
                 self._wake.clear()
 
     def _fail(self, e: Exception) -> None:

@@ -57,7 +57,7 @@ import jax
 import numpy as np
 
 from repro.models.common import pad_to
-from repro.runtime import kvcache
+from repro.runtime import kvcache, tracing
 from repro.runtime.engine import Engine
 from repro.runtime.faults import (FaultPlan, MigrationFault,
                                   TransientStepError)
@@ -94,6 +94,10 @@ def _tok_scalar(tok) -> int:
 PRIORITY_CLASSES = ("interactive", "standard", "batch")
 PRIORITY_RANK = {c: i for i, c in enumerate(PRIORITY_CLASSES)}
 
+# engine programs with a decode half (their dispatch spans carry per-slot
+# context and budget)
+_DECODING = ("decode", "mixed", "spec")
+
 
 def _check_priority(priority: str) -> str:
     if priority not in PRIORITY_RANK:
@@ -112,6 +116,10 @@ class Request:
     arrival_step: int = 0         # virtual-clock arrival (decode steps)
     submitted_at: float = field(default_factory=time.monotonic)
     output: Optional[np.ndarray] = None
+    # per-request counters, times in seconds on time.monotonic: queue_s
+    # (submitted_at to slot admission), prefill_s (slot admission to the
+    # first emitted token), ttft_s (submitted_at to the first emitted
+    # token); the frontend adds inbox_s (its inbox to submitted_at)
     stats: Dict = field(default_factory=dict)
     # why the request retired: "stop" (EOS) | "length" (budget) | "error"
     # (quarantined: poisoned output, persistent step failure, failed
@@ -318,7 +326,7 @@ class ContinuousScheduler:
         self.stats = {
             "decode_steps": 0, "slot_steps": 0, "active_slot_steps": 0,
             "emitted": 0, "admission_rounds": 0, "in_flight_admissions": 0,
-            "prefill_calls": 0, "prefill_tokens": 0,
+            "prefill_tokens": 0,
             "prefill_chunks": 0, "chunked_admissions": 0,
             # host/device timing split (both loops): host_blocked_s sums the
             # np.asarray waits (every materialization routes through
@@ -499,6 +507,37 @@ class ContinuousScheduler:
         self._calls += 1
         return jax.random.fold_in(self._rng, self._calls)
 
+    def _dispatch(self, kind: str, call, n: int = 1, chunks=()):
+        """Runs ``call``, one engine dispatch of ``n`` steps, inside its
+        ``repro.sched.dispatch`` span.  The span's stats are the step's
+        work: for the programs that decode, each decoding slot's context
+        at step 0 and its token budget (from the host arrays), the prefill
+        ``chunks`` as (start, length) pairs, and the programs built during
+        the call."""
+        def live():
+            if kind not in _DECODING:
+                return np.zeros(0, np.int64), np.zeros(0, np.int64)
+            a = ~self.dones & (self.remaining > 0)
+            return (self.pos[a].astype(np.int64) + 1,
+                    self.remaining[a].astype(np.int64))
+
+        def live_tokens():
+            ctx, rem = live()
+            m = np.minimum(rem, n)
+            return int((m * ctx + m * (m - 1) // 2).sum())
+
+        sp = tracing.span(
+            "sched.dispatch", kind=kind, n=n, step=self.step_count,
+            live_tokens=live_tokens,
+            ctx=lambda: " ".join(map(str, live()[0])),
+            rem=lambda: " ".join(map(str, live()[1])),
+            chunks=lambda: " ".join(f"{a}:{c}" for a, c in chunks))
+        built = tracing.builds()
+        with sp:
+            out = call()
+            sp.set_metadata(built=tracing.builds() - built)
+        return out
+
     def _inflight_mask(self) -> Optional[np.ndarray]:
         """Slots with emissions still in flight (covered by an unlanded
         block's predicted-active mask) — they may not retire or be reused
@@ -676,10 +715,11 @@ class ContinuousScheduler:
             tokens[slot, : len(r.prompt)] = r.prompt
             admit[slot] = True
             plens[slot] = len(r.prompt)
-        new_tok, self.caches = self.engine.prefill_into_slots(
-            self.caches, tokens, admit, plens, self._next_rng())
+        new_tok, self.caches = self._dispatch(
+            "prefill", lambda: self.engine.prefill_into_slots(
+                self.caches, tokens, admit, plens, self._next_rng()),
+            chunks=[(0, len(r.prompt)) for _, r in pairs])
         self.stats["prefill_tokens"] += int(plens[admit].sum())
-        self.stats["prefill_calls"] += 1
         self._admission_mark = True
         self._finish_admission([s for s, _ in pairs], [r for _, r in pairs],
                                admit, np.array(new_tok))
@@ -690,29 +730,32 @@ class ContinuousScheduler:
         decode state.  ``ttft_s`` is stamped HERE — under chunked admission
         that is the step whose chunk completed the prompt, so TTFT reflects
         the first token actually *emitted*, not slot assignment."""
-        adm = admit if new_tok.ndim == 1 else admit[:, None]
-        self.tok = np.where(adm, new_tok, self.tok)
-        for slot, r in zip(free, chosen):
-            t = _tok_scalar(new_tok[slot])
-            if not 0 <= t < self.vocab:
-                # poisoned prefill output (the int32 image of non-finite
-                # logits): quarantine before the garbage id reaches the
-                # stream — the first decode dispatch masks the slot out
-                self._quarantine_slot(
-                    slot, "error", f"poisoned prefill token {t}")
-                continue
-            self.slots[slot].toks.append(
-                t if self.ncb == 1
-                else np.asarray(new_tok[slot], np.int32).copy())
-            if self.on_token is not None:
-                self.on_token(r.rid, t)
-            self.pos[slot] = len(r.prompt) + self._prefix
-            self.remaining[slot] = r.max_new - 1
-            self.eos[slot] = -1 if r.eos_id is None else r.eos_id
-            self.dones[slot] = (r.max_new <= 1) or (
-                r.eos_id is not None and t == r.eos_id)
-            r.stats["ttft_s"] = time.monotonic() - r.submitted_at
-            self.stats["emitted"] += 1
+        with tracing.span("sched.emit"):
+            adm = admit if new_tok.ndim == 1 else admit[:, None]
+            self.tok = np.where(adm, new_tok, self.tok)
+            for slot, r in zip(free, chosen):
+                t = _tok_scalar(new_tok[slot])
+                if not 0 <= t < self.vocab:
+                    # poisoned prefill output (the int32 image of
+                    # non-finite logits): quarantine before the garbage id
+                    # reaches the stream — the first decode dispatch masks
+                    # the slot out
+                    self._quarantine_slot(
+                        slot, "error", f"poisoned prefill token {t}")
+                    continue
+                self.slots[slot].toks.append(
+                    t if self.ncb == 1
+                    else np.asarray(new_tok[slot], np.int32).copy())
+                if self.on_token is not None:
+                    self.on_token(r.rid, t)
+                self.pos[slot] = len(r.prompt) + self._prefix
+                self.remaining[slot] = r.max_new - 1
+                self.eos[slot] = -1 if r.eos_id is None else r.eos_id
+                self.dones[slot] = (r.max_new <= 1) or (
+                    r.eos_id is not None and t == r.eos_id)
+                r.stats["ttft_s"] = time.monotonic() - r.submitted_at
+                r.stats["prefill_s"] = r.stats["ttft_s"] - r.stats["queue_s"]
+                self.stats["emitted"] += 1
 
     def _decode_inputs(self):
         """Decode-state inputs for the next engine dispatch: the newest
@@ -729,7 +772,8 @@ class ContinuousScheduler:
         single choke point both loops materialize through, so the bench's
         blocked-time comparison is honest."""
         t0 = time.monotonic()
-        out = [np.asarray(a) for a in arrs]
+        with tracing.span("sched.sync"):
+            out = [np.asarray(a) for a in arrs]
         now = time.monotonic()
         self.stats["host_blocked_s"] += now - t0
         # liveness: engine outputs just became host-visible — the watchdog
@@ -749,8 +793,10 @@ class ContinuousScheduler:
         """Pre-decode capacity hook (paged backend: block allocation)."""
 
     def _decode_block(self, n: int) -> None:
-        self._ensure_capacity(n)
-        toks, self.caches, pos, done, remaining = self._run_decode(n)
+        with tracing.span("sched.capacity"):
+            self._ensure_capacity(n)
+        toks, self.caches, pos, done, remaining = self._dispatch(
+            "decode", lambda: self._run_decode(n), n)
         self._apply_decode(self._materialize(toks), pos, done, remaining, n)
 
     # -- overlapped loop (dispatch-ahead + one-step-late landing) -----------
@@ -761,7 +807,8 @@ class ContinuousScheduler:
         is the only surprise, rolled back at ``_land_next``).  The virtual
         clock advances at dispatch so arrival admissibility matches the
         blocking loop decision-for-decision."""
-        self._ensure_capacity(n)
+        with tracing.span("sched.capacity"):
+            self._ensure_capacity(n)
         active = (~self.dones) & (self.remaining > 0)
         if not self._pipeline:
             # pipeline was drained: the host arrays ARE the exact frontier
@@ -769,7 +816,8 @@ class ContinuousScheduler:
             self._exact_pos = self.pos.copy()
             self._exact_dones = self.dones.copy()
             self._exact_rem = self.remaining.copy()
-        toks, self.caches, pos, done, remaining = self._run_decode(n)
+        toks, self.caches, pos, done, remaining = self._dispatch(
+            "decode", lambda: self._run_decode(n), n)
         self._pipeline.append(_Pending(
             toks=toks, pos=pos, done=done, remaining=remaining, n=n,
             base_step=self.step_count,
@@ -813,35 +861,38 @@ class ContinuousScheduler:
                 toks, rec.base_step,
                 active=(np.array([s.req is not None for s in rec.slots])
                         & ~self._exact_dones & (self._exact_rem > 0)))
-        # exact emission replay off the rolling landed pre-state
-        cur_done = self._exact_dones.copy()
-        cur_rem = self._exact_rem.copy()
-        emitted_block = 0
-        poisoned: Dict[int, int] = {}
-        for s in range(rec.n):
-            for i, slot in enumerate(rec.slots):
-                if slot.req is None or cur_done[i] or cur_rem[i] <= 0:
-                    continue
-                t = _tok_scalar(toks[s, i])
-                if not 0 <= t < self.vocab:
-                    # poisoned step output: freeze the slot NOW so no later
-                    # token from this block reaches its stream; quarantine
-                    # below, after the exact frontier is adopted
-                    poisoned[i] = t
-                    cur_done[i] = True
-                    continue
-                slot.toks.append(
-                    t if self.ncb == 1
-                    else np.asarray(toks[s, i], np.int32).copy())
-                if self.on_token is not None:
-                    self.on_token(slot.req.rid, t)
-                cur_rem[i] -= 1
-                if cur_rem[i] == 0 or (rec.eos[i] >= 0 and t == rec.eos[i]):
-                    cur_done[i] = True
-                self.stats["emitted"] += 1
-                self.stats["active_slot_steps"] += 1
-                self._tps.append(1)
-                emitted_block += 1
+        with tracing.span("sched.emit"):
+            # exact emission replay off the rolling landed pre-state
+            cur_done = self._exact_dones.copy()
+            cur_rem = self._exact_rem.copy()
+            emitted_block = 0
+            poisoned: Dict[int, int] = {}
+            for s in range(rec.n):
+                for i, slot in enumerate(rec.slots):
+                    if slot.req is None or cur_done[i] or cur_rem[i] <= 0:
+                        continue
+                    t = _tok_scalar(toks[s, i])
+                    if not 0 <= t < self.vocab:
+                        # poisoned step output: freeze the slot NOW so no
+                        # later token from this block reaches its stream;
+                        # quarantine below, after the exact frontier is
+                        # adopted
+                        poisoned[i] = t
+                        cur_done[i] = True
+                        continue
+                    slot.toks.append(
+                        t if self.ncb == 1
+                        else np.asarray(toks[s, i], np.int32).copy())
+                    if self.on_token is not None:
+                        self.on_token(slot.req.rid, t)
+                    cur_rem[i] -= 1
+                    if cur_rem[i] == 0 or (rec.eos[i] >= 0
+                                           and t == rec.eos[i]):
+                        cur_done[i] = True
+                    self.stats["emitted"] += 1
+                    self.stats["active_slot_steps"] += 1
+                    self._tps.append(1)
+                    emitted_block += 1
         # the landed arrays are the exact post-block frontier.  The device
         # never learns about host-forced retirements (quarantine/timeout),
         # so its done-mask is OR-ed with the forced set — otherwise a block
@@ -897,47 +948,50 @@ class ContinuousScheduler:
         """Host bookkeeping for ``n`` executed decode steps (toks (n, B)):
         replay the device's masking rule to tell real emissions from
         frozen-slot repeats; final state must agree with the device's."""
-        if self.faults:
-            toks = self.faults.corrupt_tokens(
-                toks, self.step_count,
-                active=(np.array([s.req is not None for s in self.slots])
-                        & ~self.dones & (self.remaining > 0)))
-        cur_done = self.dones.copy()
-        cur_rem = self.remaining.copy()
-        emitted_block = 0
-        poisoned: Dict[int, int] = {}
-        for s in range(n):
-            for i, slot in enumerate(self.slots):
-                if slot.req is None or cur_done[i] or cur_rem[i] <= 0:
-                    continue
-                t = _tok_scalar(toks[s, i])
-                if not 0 <= t < self.vocab:
-                    poisoned[i] = t
-                    cur_done[i] = True
-                    continue
-                slot.toks.append(
-                    t if self.ncb == 1
-                    else np.asarray(toks[s, i], np.int32).copy())
-                if self.on_token is not None:
-                    self.on_token(slot.req.rid, t)
-                cur_rem[i] -= 1
-                if cur_rem[i] == 0 or (self.eos[i] >= 0 and t == self.eos[i]):
-                    cur_done[i] = True
-                self.stats["emitted"] += 1
-                self.stats["active_slot_steps"] += 1
-                self._tps.append(1)
-                emitted_block += 1
-        self.tok = toks[-1].copy()
-        self.pos = np.array(pos)
-        self.dones = np.array(done)
-        self.remaining = np.array(remaining)
-        self.step_count += n
-        self.stats["decode_steps"] += n
-        self.stats["slot_steps"] += n * self.B
-        self._note_itl(n, emissions=emitted_block)
-        for i, t in poisoned.items():
-            self._quarantine_slot(
-                i, "error", f"poisoned step output (token {t})")
+        with tracing.span("sched.emit"):
+            if self.faults:
+                toks = self.faults.corrupt_tokens(
+                    toks, self.step_count,
+                    active=(np.array([s.req is not None
+                                      for s in self.slots])
+                            & ~self.dones & (self.remaining > 0)))
+            cur_done = self.dones.copy()
+            cur_rem = self.remaining.copy()
+            emitted_block = 0
+            poisoned: Dict[int, int] = {}
+            for s in range(n):
+                for i, slot in enumerate(self.slots):
+                    if slot.req is None or cur_done[i] or cur_rem[i] <= 0:
+                        continue
+                    t = _tok_scalar(toks[s, i])
+                    if not 0 <= t < self.vocab:
+                        poisoned[i] = t
+                        cur_done[i] = True
+                        continue
+                    slot.toks.append(
+                        t if self.ncb == 1
+                        else np.asarray(toks[s, i], np.int32).copy())
+                    if self.on_token is not None:
+                        self.on_token(slot.req.rid, t)
+                    cur_rem[i] -= 1
+                    if cur_rem[i] == 0 or (self.eos[i] >= 0
+                                           and t == self.eos[i]):
+                        cur_done[i] = True
+                    self.stats["emitted"] += 1
+                    self.stats["active_slot_steps"] += 1
+                    self._tps.append(1)
+                    emitted_block += 1
+            self.tok = toks[-1].copy()
+            self.pos = np.array(pos)
+            self.dones = np.array(done)
+            self.remaining = np.array(remaining)
+            self.step_count += n
+            self.stats["decode_steps"] += n
+            self.stats["slot_steps"] += n * self.B
+            self._note_itl(n, emissions=emitted_block)
+            for i, t in poisoned.items():
+                self._quarantine_slot(
+                    i, "error", f"poisoned step output (token {t})")
 
     def _note_itl(self, n: int, emissions: Optional[int] = None,
                   tokens_per_slot: Optional[List[int]] = None) -> None:
@@ -1191,7 +1245,8 @@ class ContinuousScheduler:
         emitted, so the drafter sits on the host between steps (block_steps
         does not apply while spec decode is on)."""
         K = self.spec_k
-        self._ensure_spec_capacity()       # may preempt: collect slots AFTER
+        with tracing.span("sched.capacity"):
+            self._ensure_spec_capacity()   # may preempt: collect slots AFTER
         active = self._active_slots()
         if not active:
             return
@@ -1204,7 +1259,7 @@ class ContinuousScheduler:
             vtok[active, 1:] = np.stack(
                 [self.drafter.propose(h) for h in histories])
         targets, n_emit, nxt, self.caches, pos, done, remaining = \
-            self._run_verify(vtok)
+            self._dispatch("spec", lambda: self._run_verify(vtok))
         targets, n_emit = self._materialize(targets, n_emit)
         counts = []
         for i in active:
@@ -1262,7 +1317,8 @@ class ContinuousScheduler:
         — in the same jitted program, so long-prompt admission costs decode
         at most one chunk of extra latency per token."""
         C = self.chunk
-        self._pre_mixed()                  # may preempt: assemble AFTER
+        with tracing.span("sched.capacity"):
+            self._pre_mixed()              # may preempt: assemble AFTER
         slots_p = self._prefilling()
         if not slots_p:
             # capacity pressure preempted every prefilling slot — nothing to
@@ -1288,8 +1344,10 @@ class ContinuousScheduler:
             totals[i] = off + nc
             if off + nc == plen:
                 emits.append(i)
-        ptok, toks, self.caches, pos, done, remaining = self._run_mixed(
-            tokens, admit, first, clens, starts, totals)
+        ptok, toks, self.caches, pos, done, remaining = self._dispatch(
+            "mixed", lambda: self._run_mixed(tokens, admit, first, clens,
+                                             starts, totals),
+            chunks=[(int(starts[i]), int(clens[i])) for i in slots_p])
         self._admission_mark = True        # this step carried prefill work
         self._apply_decode(self._materialize(toks)[None], pos, done,
                            remaining, 1)
@@ -1299,7 +1357,6 @@ class ContinuousScheduler:
             s.chunk_next += int(clens[i])
             self.stats["prefill_tokens"] += int(clens[i])
             self.stats["prefill_chunks"] += 1
-        self.stats["prefill_calls"] += 1
         self._post_chunks(slots_p)
         if emits:
             adm = np.zeros((self.B,), bool)
@@ -1496,8 +1553,7 @@ class ContinuousScheduler:
             if any(s.req is None or (self.dones[i] and s.chunk_next is None)
                    for i, s in enumerate(self.slots)):
                 self._drain_pipeline()
-        self._retire()
-        self._admit()
+        self._retire_and_admit()
         if self._prefilling():
             # chunked admission in flight: fused mixed steps advance one
             # chunk per slot AND one decode token per active slot (reads
@@ -1527,6 +1583,19 @@ class ContinuousScheduler:
             self._try_step(lambda: self._decode_block(n))
         return True
 
+    def _retire_and_admit(self) -> None:
+        with tracing.span("sched.retire"):
+            self._retire()
+        with tracing.span("sched.admit") as sp:
+            sp.set_metadata(admitted=self._admit())
+
+    def _round(self) -> bool:
+        """``_serve_round`` inside its ``repro.sched.round`` span, for
+        ``serve_step`` and ``run`` alike."""
+        with tracing.span("sched.round", step=self.step_count,
+                          t_mono_ns=time.monotonic_ns):
+            return self._serve_round()
+
     def _overlap_turn(self, n: int) -> None:
         self._dispatch_block(n)
         while len(self._pipeline) > 1:
@@ -1539,14 +1608,14 @@ class ContinuousScheduler:
         after new ``submit``s."""
         if self.caches is None:
             self._init_caches()
-        return self._serve_round()
+        return self._round()
 
     def run(self) -> List[Request]:
         """Serve until queue and slots drain; returns requests in completion
         order."""
         if self.caches is None:
             self._init_caches()
-        while self._serve_round():
+        while self._round():
             pass
         self._drain_pipeline()
         self._retire()
@@ -1639,8 +1708,7 @@ class PagedContinuousScheduler(ContinuousScheduler):
         self.slot_blocks: List[List[int]] = [[] for _ in range(n_slots)]
         self.stats.update({
             "prefill_tokens_saved": 0, "preemptions": 0,
-            "shared_block_hits": 0, "blocks_hwm": 0, "blocks_in_use": 0,
-            "deferred_admissions": 0,
+            "shared_block_hits": 0, "blocks_hwm": 0,
         })
 
     # -- geometry ---------------------------------------------------------
@@ -1648,9 +1716,8 @@ class PagedContinuousScheduler(ContinuousScheduler):
         return slot // (self.B // self.n_shards)
 
     def _note_usage(self) -> None:
-        used = self.alloc.total_used()
-        self.stats["blocks_in_use"] = used
-        self.stats["blocks_hwm"] = max(self.stats["blocks_hwm"], used)
+        self.stats["blocks_hwm"] = max(self.stats["blocks_hwm"],
+                                       self.alloc.total_used())
 
     def submit(self, prompt: np.ndarray, max_new: int,
                eos_id: Optional[int] = None, arrival_step: int = 0,
@@ -1830,11 +1897,9 @@ class PagedContinuousScheduler(ContinuousScheduler):
                     and self.alloc.free_count(shard) - need
                     < self.reserve_blocks):
                 # the blocks exist but are held for interactive admissions
-                self.stats["deferred_admissions"] += 1
                 break
             fresh = self.alloc.alloc(shard, need)
             if fresh is None:
-                self.stats["deferred_admissions"] += 1
                 break
             if shared:
                 self.alloc.incref(shard, shared)
@@ -1901,10 +1966,12 @@ class PagedContinuousScheduler(ContinuousScheduler):
         # scatter cannot touch a live slot's blocks (their pad-token K/V
         # sinks into the null block; their forward output is discarded)
         bt_w = np.where(admit[:, None], self.bt, kvcache.NULL_BLOCK).astype(np.int32)
-        new_tok, self.caches = self.engine.prefill_into_slots_paged(
-            self.caches, tokens, admit, plens, starts, totals, bt_w,
-            self._next_rng())
-        self.stats["prefill_calls"] += 1
+        new_tok, self.caches = self._dispatch(
+            "prefill", lambda: self.engine.prefill_into_slots_paged(
+                self.caches, tokens, admit, plens, starts, totals, bt_w,
+                self._next_rng()),
+            chunks=[(starts_of[r.rid], len(r.prompt) - starts_of[r.rid])
+                    for _, r in pairs])
         self._admission_mark = True
         # publish the freshly-prefilled full prompt blocks for reuse
         if self.prefix_cache:
@@ -2178,9 +2245,11 @@ class DisaggScheduler(PagedContinuousScheduler):
                 emits.append(i)
         bt_w = np.where(admit[:, None], self.bt,
                         kvcache.NULL_BLOCK).astype(np.int32)
-        ptok, self.caches = self.engine.chunk_slots_paged(
-            self.caches, tokens, admit, first, clens, starts, totals, bt_w,
-            self._next_rng())
+        ptok, self.caches = self._dispatch(
+            "chunk", lambda: self.engine.chunk_slots_paged(
+                self.caches, tokens, admit, first, clens, starts, totals,
+                bt_w, self._next_rng()),
+            chunks=[(int(starts[i]), int(clens[i])) for i in slots_p])
         self._admission_mark = True       # this round carried prefill work
         for i in slots_p:
             s = self.slots[i]
@@ -2188,7 +2257,6 @@ class DisaggScheduler(PagedContinuousScheduler):
             s.chunk_next += int(clens[i])
             self.stats["prefill_tokens"] += int(clens[i])
             self.stats["prefill_chunks"] += 1
-        self.stats["prefill_calls"] += 1
         self.stats["prefill_steps"] += 1
         self.stats["prefill_slot_busy"] += len(slots_p)
         self.stats["prefill_slot_total"] += len(self._pf_slots)
@@ -2221,6 +2289,7 @@ class DisaggScheduler(PagedContinuousScheduler):
         if self.on_token is not None:
             self.on_token(r.rid, tok)
         r.stats["ttft_s"] = time.monotonic() - r.submitted_at
+        r.stats["prefill_s"] = r.stats["ttft_s"] - r.stats["queue_s"]
         self.stats["emitted"] += 1
         if r.max_new <= 1 or (r.eos_id is not None and tok == r.eos_id):
             # nothing left to decode: complete off the prefill pool (the
@@ -2364,8 +2433,9 @@ class DisaggScheduler(PagedContinuousScheduler):
         per = self.alloc.blocks_per_shard
         src = [sh * per + b for _, sh, b, _, _ in self._mig_queue]
         dst = [sh * per + b for _, _, _, sh, b in self._mig_queue]
-        self.caches = self.engine.migrate_blocks(self.caches, src, dst,
-                                                 land, totals)
+        self.caches = self._dispatch(
+            "migrate", lambda: self.engine.migrate_blocks(
+                self.caches, src, dst, land, totals))
         for _, sh, b, _, _ in self._mig_queue:
             self.alloc.end_migration(sh, [b])
         n = len(self._mig_queue)
@@ -2508,8 +2578,7 @@ class DisaggScheduler(PagedContinuousScheduler):
             # a migration landing rewrites a decode slot's position row on
             # the host — exact state must merge before the next dispatch
             self._drain_pipeline()
-        self._retire()
-        self._admit()
+        self._retire_and_admit()
         did_prefill = bool(self._try_step(self._chunk_step))
         self._advance_handoffs()
         self._run_migrations()
@@ -2560,13 +2629,3 @@ class DisaggScheduler(PagedContinuousScheduler):
         else:
             self._stall, self._stall_sig = 0, sig
         return True
-
-    def run(self) -> List[Request]:
-        """Serve until queue, slots, and migration pipeline drain."""
-        if self.caches is None:
-            self._init_caches()
-        while self._serve_round():
-            pass
-        self._drain_pipeline()
-        self._retire()
-        return self.done

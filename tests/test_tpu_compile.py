@@ -13,6 +13,7 @@ The topology is described inside a module-scoped fixture (never at import),
 so that only the test worker given this file loads the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -59,6 +60,13 @@ def _compile(fn, sharding, *shapes):
     return hlo
 
 
+def _custom_call_named(hlo, name):
+    """The kernel's custom call carries ``name``: a profiler trace names the
+    kernel's operation after it, and the paged kernels are found by it."""
+    return re.search(rf"^\s*(ROOT )?%{name}(\.\d+)? = .* custom-call\(",
+                     hlo, re.M) is not None
+
+
 def test_decode_attention(one_chip):
     _compile(lambda q, k, v, valid: decode_attention.decode_attention_partial(
         q, k, v, valid, SCALE, interpret=False), one_chip,
@@ -69,7 +77,7 @@ def test_decode_attention(one_chip):
 
 
 def test_paged_decode_attention(one_chip):
-    _compile(lambda q, kp, vp, bt, valid:
+    hlo = _compile(lambda q, kp, vp, bt, valid:
              decode_attention.paged_decode_attention_partial(
                  q, kp, vp, bt, valid, SCALE, interpret=False), one_chip,
              ((SLOTS, HQ, 1, HD), jnp.bfloat16),
@@ -77,6 +85,7 @@ def test_paged_decode_attention(one_chip):
              ((POOL, HKV, BLOCK, HD), jnp.bfloat16),
              ((SLOTS, NBPS), jnp.int32),
              ((SLOTS, STRIPE), jnp.bool_))
+    assert _custom_call_named(hlo, "paged_decode_attention_partial")
 
 
 def test_flash_prefill(one_chip):
@@ -98,13 +107,14 @@ def test_flash_verify(one_chip):
 
 
 def test_paged_flash_prefill(one_chip):
-    _compile(lambda q, kp, vp, bt, qp: prefill_attention.paged_flash_prefill(
+    hlo = _compile(lambda q, kp, vp, bt, qp: prefill_attention.paged_flash_prefill(
         q, kp, vp, bt, qp, SCALE, interpret=False), one_chip,
         ((SLOTS, HQ, CHUNK, HD), jnp.bfloat16),
         ((POOL, HKV, BLOCK, HD), jnp.bfloat16),
         ((POOL, HKV, BLOCK, HD), jnp.bfloat16),
         ((SLOTS, NBPS), jnp.int32),
         ((SLOTS, CHUNK), jnp.int32))
+    assert _custom_call_named(hlo, "paged_flash_prefill")
 
 
 @pytest.mark.parametrize("t", [SLOTS, CHUNK])
