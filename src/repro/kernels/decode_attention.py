@@ -131,55 +131,148 @@ def decode_attention_partial(
 
 
 # ---------------------------------------------------------------------------
-# Paged variant: gather K/V block-by-block through the slot's block table
+# Paged variant: a slot's live runs of pages, all KV heads at once
 # ---------------------------------------------------------------------------
 
+STEP_TOKENS = 512        # tokens one step aims to attend
+KV_VMEM_BYTES = 8 << 20  # K and V step buffers, double-buffered, at most
 
-def _paged_decode_kernel(bt_ref, q_ref, k_ref, v_ref, valid_ref,
-                         m_ref, l_ref, acc_ref, ms_ref, ls_ref, as_ref,
-                         *, scale: float, n_s: int):
-    """Grid (b*hkv, blocks_per_slot).  The block table is a SCALAR-PREFETCH
-    operand: the index map of K/V dereferences it to DMA the j-th logical
-    block's physical (block_size, hd) slab — the kernel never sees a dense
-    per-slot cache, so HBM traffic is one read of the blocks that actually
-    hold data.  Validity is per block: slabs whose mask is entirely dead
-    (unallocated / beyond the slot's length -> null block) skip the flash
-    update altogether, moving position masking to block granularity."""
-    del bt_ref  # consumed by the index maps
-    j = pl.program_id(1)
 
-    @pl.when(j == 0)
-    def _init():
-        ms_ref[...] = jnp.full_like(ms_ref, NEG)
-        ls_ref[...] = jnp.zeros_like(ls_ref)
-        as_ref[...] = jnp.zeros_like(as_ref)
+def pages_per_step(nbps: int, hkv: int, bs: int, hd: int,
+                   itemsize: int) -> int:
+    """Pages one step of the paged kernel copies and attends: about
+    ``STEP_TOKENS`` tokens, no more than the slot's ``nbps`` pages, and few
+    enough that K and V, double-buffered, fit ``KV_VMEM_BYTES`` (a page is
+    all ``hkv`` heads: ``hkv * bs * hd * itemsize`` bytes)."""
+    page = hkv * bs * hd * itemsize
+    return max(1, min(nbps, STEP_TOKENS // bs, KV_VMEM_BYTES // (4 * page)))
 
-    ok = valid_ref[...] != 0                             # (1, bs)
 
-    @pl.when(jnp.any(ok))
-    def _update():
-        q = q_ref[...].astype(jnp.float32)               # (g, hd)
-        k = k_ref[...].astype(jnp.float32)               # (bs, hd)
-        v = v_ref[...].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        s = jnp.where(ok, s, NEG)
-        m_prev = ms_ref[...][:, 0]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.where(ok, jnp.exp(s - m_new[:, None]), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = ls_ref[...][:, 0] * corr + p.sum(axis=1)
-        acc_new = as_ref[...] * corr[:, None] + jnp.dot(
-            p, v, preferred_element_type=jnp.float32
-        )
-        ms_ref[...] = m_new[:, None]
-        ls_ref[...] = l_new[:, None]
-        as_ref[...] = acc_new
+def _live_pages(valid: jax.Array, bs: int) -> jax.Array:
+    """(b, S) position mask -> flat int32 (3b,): per slot the live page
+    range [lo, hi) (pages holding a valid position; (0, 0) when none) and
+    the next slot after it with a live range (b when none)."""
+    b = valid.shape[0]
+    page = valid.reshape(b, -1, bs).any(axis=-1)           # (b, nbps)
+    nbps = page.shape[1]
+    has = page.any(axis=1)
+    lo = jnp.where(has, jnp.argmax(page, axis=1), 0)
+    hi = jnp.where(has, nbps - jnp.argmax(page[:, ::-1], axis=1), 0)
+    idx = jnp.where(has, jnp.arange(b), b)
+    nxt = jnp.append(jax.lax.cummin(idx, reverse=True)[1:], b)
+    return jnp.stack([lo, hi, nxt], axis=1).reshape(-1).astype(jnp.int32)
 
-    @pl.when(j == n_s - 1)
-    def _emit():
-        m_ref[...] = ms_ref[...]
-        l_ref[...] = ls_ref[...]
-        acc_ref[...] = as_ref[...]
+
+def _paged_decode_kernel(bt_ref, live_ref, q_ref, valid_ref, kp_ref, vp_ref,
+                         m_ref, l_ref, acc_ref, k_buf, v_buf, sems, state,
+                         *, scale: float, pages: int, nbps: int):
+    """Grid (b,): grid step i loops over slot i's live steps only; step j
+    attends pages [j*P, (j+1)*P) of the slot's view, all KV heads at once.
+    The pool stays in HBM (``pl.ANY``); the kernel copies a step's live
+    pages itself through the block table (scalar prefetch) into one of two
+    VMEM buffers, and starts the next live step's copies (the same slot's
+    next step, else the next slot's first) before it waits on its own.
+    The live steps are those that hold the slot's live page range [lo, hi)
+    (``live_ref``); inside them the per-position mask still applies, so
+    holes and partial pages stay exact.  ``state`` (SMEM) holds the buffer
+    of the current step and whether a copy has been started yet."""
+    import jax.experimental.pallas.tpu as pltpu
+
+    i = pl.program_id(0)
+    b = pl.num_programs(0)
+    hkv = m_ref.shape[0]
+
+    def page_range(slot):
+        return live_ref[3 * slot], live_ref[3 * slot + 1]
+
+    def step_range(slot):
+        lo, hi = page_range(slot)
+        return lo // pages, (hi + pages - 1) // pages
+
+    def copies(slot, step, buf):
+        """(live, K copy, V copy) per page of (slot, step) into ``buf``."""
+        lo, hi = page_range(slot)
+        out = []
+        for p in range(pages):
+            page = step * pages + p
+            blk = bt_ref[slot * nbps + page]
+            out.append(((page >= lo) & (page < hi),
+                        pltpu.make_async_copy(kp_ref.at[blk], k_buf.at[buf, p],
+                                              sems.at[0, buf]),
+                        pltpu.make_async_copy(vp_ref.at[blk], v_buf.at[buf, p],
+                                              sems.at[1, buf])))
+        return out
+
+    def start(slot, step, buf):
+        for live, ck, cv in copies(slot, step, buf):
+            @pl.when(live)
+            def _():
+                ck.start()
+                cv.start()
+
+    def wait(slot, step, buf):
+        for p, (live, ck, cv) in enumerate(copies(slot, step, buf)):
+            @pl.when(live)
+            def _():
+                ck.wait()
+                cv.wait()
+
+            # a page not copied holds stale VMEM; its positions are masked,
+            # but p @ v would still carry a NaN there into the sum
+            @pl.when(jnp.logical_not(live))
+            def _():
+                v_buf[buf, p] = jnp.zeros(v_buf.shape[2:], v_buf.dtype)
+
+    @pl.when(i == 0)
+    def _first():
+        state[1] = 0
+
+    m_ref[...] = jnp.full_like(m_ref, NEG)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    s_lo, s_hi = step_range(i)
+
+    def step(j, carry):
+        @pl.when(state[1] == 0)          # the grid's first live step
+        def _():
+            start(i, j, 0)
+            state[0] = 0
+            state[1] = 1
+
+        buf = state[0]
+        last = j + 1 >= s_hi
+        ni = jnp.where(last, live_ref[3 * i + 2], i)
+        nj = jnp.where(last, step_range(jnp.minimum(ni, b - 1))[0], j + 1)
+
+        @pl.when(ni < b)
+        def _():
+            start(ni, nj, 1 - buf)
+            state[0] = 1 - buf
+
+        wait(i, j, buf)
+        ok = valid_ref[j] != 0                            # (1, P*bs)
+        n_tok = ok.shape[1]
+        dt = jnp.promote_types(q_ref.dtype, k_buf.dtype)
+        for h in range(hkv):
+            q = q_ref[h].astype(dt)                       # (g, hd)
+            k = k_buf[buf, :, h].astype(dt).reshape(n_tok, -1)
+            v = v_buf[buf, :, h].astype(jnp.float32).reshape(n_tok, -1)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale   # (g, P*bs)
+            s = jnp.where(ok, s, NEG)
+            m_prev = m_ref[h]                             # (g, 1)
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            # explicit zeroing: on a fully-masked row exp(NEG - NEG) is 1
+            p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * corr + p.sum(axis=1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * corr + jnp.dot(
+                p, v, preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
+        return carry
+
+    jax.lax.fori_loop(s_lo, s_hi, step, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -200,47 +293,53 @@ def paged_decode_attention_partial(
     nb, hkv, bs, _ = kp.shape
     nbps = bt.shape[1]
     g = hq // hkv
-    qg = q.reshape(b, hkv, g, hd).reshape(b * hkv, g, hd)
-    vmask = valid.reshape(b, nbps, 1, bs).astype(jnp.int32)  # see above
+    P = pages_per_step(nbps, hkv, bs, hd, kp.dtype.itemsize)
+    n_s = -(-nbps // P)
+    pad = n_s * P - nbps                 # dead pages: the null block, masked
+    live = _live_pages(valid, bs)
+    bt = jnp.pad(bt.astype(jnp.int32), ((0, 0), (0, pad))).reshape(-1)
+    vmask = jnp.pad(valid, ((0, 0), (0, pad * bs))).astype(jnp.int32)
+    vmask = vmask.reshape(b, n_s, 1, P * bs)   # see the dense kernel
+    qg = q.reshape(b, hkv, g, hd)
 
+    per_slot = lambda i, bt_ref, live_ref: (i, 0, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b * hkv, nbps),
+        num_scalar_prefetch=2,
+        grid=(b,),
         in_specs=[
-            pl.BlockSpec((None, g, hd), lambda i, j, bt_ref: (i, 0, 0)),
-            pl.BlockSpec((None, None, bs, hd),
-                         lambda i, j, bt_ref: (bt_ref[i // hkv, j],
-                                               i % hkv, 0, 0)),
-            pl.BlockSpec((None, None, bs, hd),
-                         lambda i, j, bt_ref: (bt_ref[i // hkv, j],
-                                               i % hkv, 0, 0)),
-            pl.BlockSpec((None, None, 1, bs),
-                         lambda i, j, bt_ref: (i // hkv, j, 0, 0)),
+            pl.BlockSpec((None, hkv, g, hd), per_slot),
+            pl.BlockSpec((None, n_s, 1, P * bs), per_slot),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            pl.BlockSpec((None, g, 1), lambda i, j, bt_ref: (i, 0, 0)),
-            pl.BlockSpec((None, g, 1), lambda i, j, bt_ref: (i, 0, 0)),
-            pl.BlockSpec((None, g, hd), lambda i, j, bt_ref: (i, 0, 0)),
+            pl.BlockSpec((None, hkv, g, 1), per_slot),
+            pl.BlockSpec((None, hkv, g, 1), per_slot),
+            pl.BlockSpec((None, hkv, g, hd), per_slot),
         ],
         scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, hd), jnp.float32),
+            pltpu.VMEM((2, P, hkv, bs, hd), kp.dtype),
+            pltpu.VMEM((2, P, hkv, bs, hd), vp.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((2,), jnp.int32),
         ],
     )
     m, l, acc = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, scale=scale, n_s=nbps),
+        functools.partial(_paged_decode_kernel, scale=scale, pages=P,
+                          nbps=n_s * P),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b * hkv, g, 1), jnp.float32),
-            jax.ShapeDtypeStruct((b * hkv, g, 1), jnp.float32),
-            jax.ShapeDtypeStruct((b * hkv, g, hd), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, g, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, g, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, g, hd), jnp.float32),
         ],
+        # the copies of one step are started by the step before it, across
+        # slots too, so the grid runs in order
         compiler_params=compat.tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
-    )(bt.astype(jnp.int32), qg, kp, vp, vmask)
+    )(bt, live, qg, vmask, kp, vp)
     m = m.reshape(b, hq, 1)
     l = l.reshape(b, hq, 1)
     acc = acc.reshape(b, hq, 1, hd)

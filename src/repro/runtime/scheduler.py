@@ -56,6 +56,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
+from repro.kernels import decode_attention
 from repro.models.common import pad_to
 from repro.runtime import kvcache, tracing
 from repro.runtime.engine import Engine
@@ -512,8 +513,11 @@ class ContinuousScheduler:
         ``repro.sched.dispatch`` span.  The span's stats are the step's
         work: for the programs that decode, each decoding slot's context
         at step 0 and its token budget (from the host arrays), the prefill
-        ``chunks`` as (start, length) pairs, and the programs built during
-        the call."""
+        ``chunks`` as (start, length) pairs, the programs built during
+        the call, and where the decode and mixed programs run the paged
+        decode kernel, its steps per layer that copy and compute for those
+        slots (``kv_steps``) out of the steps a walk of every slot's whole
+        view would take (``kv_grid``)."""
         def live():
             if kind not in _DECODING:
                 return np.zeros(0, np.int64), np.zeros(0, np.int64)
@@ -526,17 +530,30 @@ class ContinuousScheduler:
             m = np.minimum(rem, n)
             return int((m * ctx + m * (m - 1) // 2).sum())
 
+        def kv(stat):
+            walk = self._kv_walk() if kind in ("decode", "mixed") else None
+            return None if walk is None else stat(*walk)
+
         sp = tracing.span(
             "sched.dispatch", kind=kind, n=n, step=self.step_count,
             live_tokens=live_tokens,
             ctx=lambda: " ".join(map(str, live()[0])),
             rem=lambda: " ".join(map(str, live()[1])),
-            chunks=lambda: " ".join(f"{a}:{c}" for a, c in chunks))
+            chunks=lambda: " ".join(f"{a}:{c}" for a, c in chunks),
+            kv_steps=lambda: kv(lambda tokens, n_s: int(
+                (-(-live()[0] // tokens)).sum())),
+            kv_grid=lambda: kv(lambda tokens, n_s: self.B * n_s))
         built = tracing.builds()
         with sp:
             out = call()
             sp.set_metadata(built=tracing.builds() - built)
         return out
+
+    def _kv_walk(self) -> Optional[Tuple[int, int]]:
+        """(tokens per step, steps per slot's whole view) of the paged
+        decode kernel, where the decode programs run it; None here (dense
+        caches)."""
+        return None
 
     def _inflight_mask(self) -> Optional[np.ndarray]:
         """Slots with emissions still in flight (covered by an unlanded
@@ -1712,6 +1729,22 @@ class PagedContinuousScheduler(ContinuousScheduler):
         })
 
     # -- geometry ---------------------------------------------------------
+    def _kv_walk(self) -> Optional[Tuple[int, int]]:
+        """The paged decode kernel's walk: P pages of ``bs`` tokens a step
+        and ceil(view_blocks / P) steps to a slot's whole view, with P from
+        the kernel's own helper; None where decode attention does not run
+        the kernel (no Pallas, int8 KV, latent or no K/V pools)."""
+        par = self.engine.ctx.parallel
+        pools = [leaf for path, leaf
+                 in jax.tree_util.tree_leaves_with_path(self.caches)
+                 if getattr(path[-1], "key", None) == "k"]
+        if not (par.use_pallas and pools) or par.kv_quant:
+            return None
+        P = decode_attention.pages_per_step(
+            self.view_blocks, self.engine.ctx.plan.local_kv, self.bs,
+            pools[0].shape[-1], pools[0].dtype.itemsize)
+        return P * self.bs, -(-self.view_blocks // P)
+
     def _shard_of(self, slot: int) -> int:
         return slot // (self.B // self.n_shards)
 

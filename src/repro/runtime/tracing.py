@@ -6,7 +6,8 @@ TraceMe events, so they share the profiler's clock with the device planes:
 an idle gap on the device falls inside the named host work that caused it.
 Keyword ``stats`` are written as the event's stats; a stat given as a
 zero-argument callable is evaluated only while a profiler records, so
-costly stats (per-slot lists) cost nothing otherwise.
+costly stats (per-slot lists) cost nothing otherwise; one that evaluates
+to None is left out of the event.
 
 A recording profiler is the only switch: with none recording, ``span``
 returns one shared no-op context manager and evaluates nothing.  The
@@ -33,8 +34,12 @@ The spans and stats, by name (stats in parentheses):
   ``prefill``, ``chunk``, ``migrate``), ``n`` (steps), ``step``,
   ``live_tokens`` (live context summed over the steps), ``ctx`` and
   ``rem`` (per decoding slot, space-separated: context at step 0 and token
-  budget), ``chunks`` (``start:len`` per prefill chunk) and ``built``
-  (programs built during the call).
+  budget), ``chunks`` (``start:len`` per prefill chunk), ``built``
+  (programs built during the call) and, where the decode and mixed
+  programs run the paged decode kernel, ``kv_steps`` (its steps per layer
+  that copy and compute for the decoding slots, at step 0) and
+  ``kv_grid`` (slots x the steps of a whole view: a walk of every slot's
+  whole view, per layer).
 """
 from __future__ import annotations
 
@@ -66,8 +71,9 @@ def span(name: str, **stats):
     """A ``repro.<name>`` span while a profiler records, else the no-op."""
     if not TraceAnnotation.is_enabled():
         return _NOOP
+    stats = {k: v() if callable(v) else v for k, v in stats.items()}
     return TraceAnnotation(PREFIX + name, **{
-        k: v() if callable(v) else v for k, v in stats.items()})
+        k: v for k, v in stats.items() if v is not None})
 
 
 _built = 0

@@ -62,15 +62,31 @@ def test_decode_attention_sweep(b, hq, hkv, S, hd, dtype):
     np.testing.assert_allclose(np.asarray(m1), np.asarray(m2), atol=tol, rtol=tol)
 
 
-@pytest.mark.parametrize("b,hq,hkv,bs,nbps,hd", [
-    (1, 4, 4, 16, 4, 64), (2, 8, 2, 8, 6, 64), (3, 4, 1, 32, 2, 128),
+@pytest.mark.parametrize("b,hq,hkv,bs,nbps,hd,spans", [
+    pytest.param(1, 4, 4, 16, 4, 64, None, id="1-4-4-16-4-64"),
+    pytest.param(2, 8, 2, 8, 6, 64, None, id="2-8-2-8-6-64"),
+    pytest.param(3, 4, 1, 32, 2, 128, None, id="3-4-1-32-2-128"),
+    # 40 pages in steps of 32: the second step half padding; both slots
+    # end mid-step
+    pytest.param(2, 8, 2, 16, 40, 64, (((0, 600),), ((0, 17),)),
+                 id="padded-steps"),
+    # an empty slot between two live ones walks nothing (steps of 8 pages)
+    pytest.param(3, 4, 1, 64, 10, 128, (((0, 300),), (), ((0, 640),)),
+                 id="empty-slot"),
+    # windowed masks: live ranges that start after page 0, one with a hole
+    pytest.param(2, 8, 2, 64, 24, 64,
+                 (((700, 1200),), ((520, 530), (1400, 1500))), id="window"),
+    pytest.param(2, 10, 2, 64, 10, 64, (((0, 530),), ((100, 300),)),
+                 id="g5"),
+    pytest.param(2, 4, 4, 64, 10, 64, (((0, 513),), ((0, 1),)), id="g1"),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_decode_attention_matches_dense_kernel(b, hq, hkv, bs, nbps, hd,
-                                                     dtype):
-    """Pool + block-table gather (scalar-prefetch index maps) must agree
-    with the dense kernel run on the gathered view, including a fully-dead
-    trailing block (per-block masking skips its flash update)."""
+                                                     spans, dtype):
+    """Pool + block-table gather (a grid step per run of pages, copied by
+    hand) must agree with the dense kernel run on the gathered view.  By
+    default each slot is a random prefix whose last block is fully dead;
+    ``spans`` gives each slot's valid [start, end) position ranges."""
     S = nbps * bs
     ks = jax.random.split(jax.random.key(b * S + hd), 3)
     nb = 1 + b * nbps
@@ -80,8 +96,15 @@ def test_paged_decode_attention_matches_dense_kernel(b, hq, hkv, bs, nbps, hd,
     bt = jnp.asarray(rng.permutation(np.arange(1, nb))[: b * nbps]
                      .reshape(b, nbps).astype(np.int32))
     q = jax.random.normal(ks[2], (b, hq, 1, hd)).astype(dtype)
-    lens = rng.integers(1, S - bs + 1, size=b)       # last block fully dead
-    valid = jnp.asarray(np.arange(S)[None, :] < lens[:, None])
+    if spans is None:
+        lens = rng.integers(1, S - bs + 1, size=b)   # last block fully dead
+        valid = np.arange(S)[None, :] < lens[:, None]
+    else:
+        valid = np.zeros((b, S), bool)
+        for bi, ranges in enumerate(spans):
+            for lo, hi in ranges:
+                valid[bi, lo:hi] = True
+    valid = jnp.asarray(valid)
     scale = 1.0 / np.sqrt(hd)
     m1, l1, a1 = ops.paged_decode_attention(q, kp, vp, bt, valid, scale)
     view = jnp.take(kp, bt, axis=0).transpose(0, 2, 1, 3, 4).reshape(b, hkv, S, hd)
@@ -94,6 +117,8 @@ def test_paged_decode_attention_matches_dense_kernel(b, hq, hkv, bs, nbps, hd,
         o2 = np.asarray(a2) / np.maximum(np.asarray(l2)[..., None], 1e-30)
         np.testing.assert_allclose(o1[bi:bi + 1], o2, atol=tol, rtol=tol)
         np.testing.assert_allclose(np.asarray(m1)[bi:bi + 1], np.asarray(m2),
+                                   atol=tol, rtol=tol)
+        np.testing.assert_allclose(np.asarray(l1)[bi:bi + 1], np.asarray(l2),
                                    atol=tol, rtol=tol)
 
 

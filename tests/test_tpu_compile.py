@@ -6,7 +6,8 @@ compiles the kernel.  These tests compile each kernel ahead of time for one
 chip of a described ``v5e:2x2`` topology, at yi-9b's published widths
 (d_model 4096, 32 q / 4 kv heads of 128, d_ff 11008, vocab 64,000; 8 slots,
 a 4,096-token cache stripe, 16-token paged blocks, 256-token prefill chunks)
-and, for the RG-LRU scan, at recurrentgemma-9b's 4,096 recurrence width.
+and, for the RG-LRU scan, at recurrentgemma-9b's 4,096 recurrence width;
+the paged decode kernel also at qwen2.5-14b's 8 kv heads.
 Nothing runs: a compile that passes says nothing about results or times.
 
 The topology is described inside a module-scoped fixture (never at import),
@@ -85,6 +86,24 @@ def test_paged_decode_attention(one_chip):
              ((POOL, HKV, BLOCK, HD), jnp.bfloat16),
              ((SLOTS, NBPS), jnp.int32),
              ((SLOTS, STRIPE), jnp.bool_))
+    assert _custom_call_named(hlo, "paged_decode_attention_partial")
+
+
+def test_paged_decode_attention_qwen_widths(one_chip):
+    """qwen2.5-14b widths: 40 q / 8 kv heads of 128 (group 5), 24 slots of
+    2,048 tokens.  Each grid step's K and V buffers hold all 8 heads, so
+    this is the widest page the kernel's VMEM budget has to take."""
+    slots, hq, hkv, stripe = 24, 40, 8, 2048
+    nbps = stripe // BLOCK
+    pool = 1 + slots * nbps
+    hlo = _compile(lambda q, kp, vp, bt, valid:
+                   decode_attention.paged_decode_attention_partial(
+                       q, kp, vp, bt, valid, SCALE, interpret=False), one_chip,
+                   ((slots, hq, 1, HD), jnp.bfloat16),
+                   ((pool, hkv, BLOCK, HD), jnp.bfloat16),
+                   ((pool, hkv, BLOCK, HD), jnp.bfloat16),
+                   ((slots, nbps), jnp.int32),
+                   ((slots, stripe), jnp.bool_))
     assert _custom_call_named(hlo, "paged_decode_attention_partial")
 
 

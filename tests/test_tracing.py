@@ -13,6 +13,7 @@ import pytest
 from jax.profiler import ProfileData
 
 from repro.configs import ParallelConfig, SamplingConfig, get_config
+from repro.kernels import decode_attention
 from repro.launch.frontend import EngineService
 from repro.launch.mesh import make_local_mesh
 from repro.runtime import tracing
@@ -107,6 +108,8 @@ def test_scheduler_spans_under_a_profiler(traced):
         assert st["built"] >= 0
     assert live == (6 - 1) + (9 - 1) + (3 - 1)
     assert sum(st["built"] for st in disp) <= built
+    # the CPU engine decodes attention without the paged decode kernel
+    assert not any("kv_steps" in st or "kv_grid" in st for st in disp)
     admitted = sum(st["admitted"] for n, st in evs
                    if n == "repro.sched.admit")
     assert admitted == 3 == len(sched.done)
@@ -149,3 +152,54 @@ def test_request_counters_on_a_served_run(engine):
         assert st["inbox_s"] >= 0
         assert 0 <= st["prefill_s"] <= st["ttft_s"] - st["queue_s"] + 1e-6
 
+
+def test_kv_walk_stats_follow_the_slots_contexts(tmp_path):
+    """Where decode attention runs the paged kernel, the decode and mixed
+    dispatch spans carry ``kv_steps`` (the kernel's steps per layer that
+    copy and compute: each decoding slot's context in steps of P pages)
+    and ``kv_grid`` (slots x the steps of a whole view), P from the
+    kernel's own helper; they are evaluated only while a profiler
+    records."""
+    cfg = get_config("yi-9b").reduced()
+    engine = Engine(cfg=cfg,
+                    parallel=ParallelConfig(tp=1, dp=1, remat=False,
+                                            use_pallas=True),
+                    sampling=SamplingConfig(greedy=True, top_k=1),
+                    mesh=make_local_mesh(1, 1), max_len=2048)
+    sched = PagedContinuousScheduler(engine, n_slots=4, block_size=16)
+    sched._init_caches()
+    pages = decode_attention.pages_per_step(
+        2048 // 16, engine.ctx.plan.local_kv, 16, cfg.resolved_head_dim, 2)
+    tokens, n_s = pages * 16, -(-(2048 // 16) // pages)
+    assert (tokens, n_s) == (512, 4)
+    # contexts 1, 512, 513 and 2048 tokens; the third slot is done
+    sched.pos = np.array([0, 511, 512, 2047], np.int32)
+    sched.dones = np.array([False, False, True, False])
+    sched.remaining = np.array([5, 5, 0, 1], np.int32)
+
+    def walk_fails():
+        raise AssertionError("evaluated with no profiler recording")
+
+    sched._kv_walk = walk_fails
+    sched._dispatch("decode", lambda: None, n=8)
+    del sched._kv_walk
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for kind in ("decode", "mixed", "spec", "prefill"):
+            sched._dispatch(kind, lambda: None, n=8)
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(os.path.join(tmp_path, "**", "*.xplane.pb"),
+                     recursive=True)[0]
+    disp = {st["kind"]: st for n, st in span_events(path)
+            if n == "repro.sched.dispatch"}
+    assert len(disp) == 4
+    for kind in ("decode", "mixed"):
+        ctx = [int(c) for c in words(disp[kind], "ctx")]
+        assert ctx == [1, 512, 2048]
+        assert disp[kind]["kv_steps"] == sum(-(-c // tokens) for c in ctx)
+        assert disp[kind]["kv_steps"] == 1 + 1 + 4
+        assert disp[kind]["kv_grid"] == 4 * n_s
+    for kind in ("spec", "prefill"):
+        assert "kv_steps" not in disp[kind] and "kv_grid" not in disp[kind]
