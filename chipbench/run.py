@@ -3,8 +3,10 @@
 
     python3 chipbench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-A cell of ``BENCHMARK.json`` names a configuration (``configs/<name>.json``)
-and a traffic mix (``traffic/<name>.json``).  The run draws the weights and
+A cell of ``BENCHMARK.json`` names a configuration (``configs/<name>.json``),
+whose Hugging Face ``model_type`` names its architecture
+(``arch/<model_type>.py``: program config, reference and counts), and a
+traffic mix (``traffic/<name>.json``).  The run draws the weights and
 the requests from ``--seed`` and serves them through the program's HTTP
 frontend on the chip: the mix's lead-in arrivals, untimed, bring the
 server to its steady state, then the window measures for ``--seconds``.
@@ -15,7 +17,8 @@ of the window.  Then it checks the served tokens against the float32
 reference and prints one JSON line as the last line of standard output.
 
 It fails, printing no result, when JAX finds no TPU or fewer chips than
-the cell asks for, or when a file the cell names is missing."""
+the cell asks for, or when a file the cell names, or its configuration's
+architecture, is missing."""
 from __future__ import annotations
 
 import time
@@ -37,6 +40,7 @@ HERE = Path(__file__).resolve().parent
 CHECKOUT = HERE.parent
 sys.path[:0] = [str(HERE), str(CHECKOUT / "src")]
 
+import archs  # noqa: E402
 from traffic import generate as G  # noqa: E402
 
 TRACE_MAX_S = 20.0          # longest traced part of the window
@@ -95,6 +99,10 @@ def load_cell(workload: str, trace: bool, root: Path = HERE) -> Cell:
     if not files:
         raise BenchError(f"no configuration {cell['config']!r}")
     cfg = load_json(root.parent / files[0])
+    try:
+        archs.find(cfg.get("model_type"))
+    except LookupError as e:
+        raise BenchError(f"configuration {files[0]}: {e}") from None
     mix = G.load_mix(cell["traffic"], root / "traffic")
     group = bench["per_layer"] if trace else bench["end_to_end"]
     metrics = [m for m in group if workload in m.get("workloads", [workload])]

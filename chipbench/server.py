@@ -27,6 +27,7 @@ from repro.models import model as M
 from repro.models.common import is_def
 from repro.runtime.engine import Engine
 
+import archs
 import weights as W
 
 SCHED_SPANS = ("serve_step", "_retire", "_admit", "_mixed_step",
@@ -34,21 +35,17 @@ SCHED_SPANS = ("serve_step", "_retire", "_admit", "_mixed_step",
 
 
 def program_config(cfg: dict) -> ModelConfig:
-    """The program's config object for a configuration file."""
-    return ModelConfig(
-        name=cfg["name"], family="dense",
-        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        head_dim=cfg.get("head_dim", 0),
-        qkv_bias=bool(cfg.get("qkv_bias", cfg.get("attention_bias", False))),
-        tie_embeddings=bool(cfg["tie_word_embeddings"]),
-        rope_theta=float(cfg["rope_theta"]),
-        rms_eps=float(cfg["rms_norm_eps"]), act="silu", gated_mlp=True)
+    """The program's config object for a configuration file: its
+    architecture's (``arch/<model_type>.py``)."""
+    return archs.load(cfg).program_config(cfg)
 
 
 def parallel_config(cfg: dict) -> ParallelConfig:
+    """The architecture's ``parallel_config`` where it gives one, else the
+    configuration's ``serving`` sizes on one data-parallel replica."""
+    arch = archs.load(cfg)
+    if hasattr(arch, "parallel_config"):
+        return arch.parallel_config(cfg)
     s = cfg["serving"]
     return ParallelConfig(tp=s["tp"], dp=1, remat=False,
                           kv_block_size=s["kv_block_size"],
@@ -77,7 +74,8 @@ def draw_weights(cfg: dict, seed: int, mesh):
     if (plan.n_heads_p != plan.n_heads or plan.vocab_p != cfg["vocab_size"]):
         raise ValueError("padded heads or vocabulary: the reference reads "
                          "no padding")
-    return W.draw(seed, M.model_defs(ctx), is_def, mesh)
+    return W.draw(seed, M.model_defs(ctx), is_def, mesh,
+                  getattr(archs.load(cfg), "STD", {}))
 
 
 def build_engine(cfg: dict, params, mesh):
